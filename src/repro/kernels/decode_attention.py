@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
@@ -163,7 +162,7 @@ def decode_attention_quant(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((group,), jnp.float32),
             pltpu.VMEM((group, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, k, v, k_scale, v_scale)
@@ -214,7 +213,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((group,), jnp.float32),
             pltpu.VMEM((group, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qg, k, v)

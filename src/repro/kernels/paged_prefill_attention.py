@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 from repro.kernels.paged_decode_attention import (
     NEG_INF,
     _assemble_kv_tile,
@@ -253,7 +252,7 @@ def _prefill_call(q, k_pages, v_pages, chunk_k, chunk_v, block_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, C, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,6 +1,6 @@
 """Async serving driver: QLM cluster behind the backpressure front end.
 
-Same reduced-model JAX cluster as ``launch/serve.py``, but driven through
+The reduced-model JAX cluster of ``launch/serve.py --reduced``, driven through
 ``serving.frontend.AsyncServer``: a bounded request queue with high/low
 backpressure watermarks and 429-style rejection, per-request deadlines
 (expired requests never dispatch), client cancellation that frees KV
@@ -44,6 +44,7 @@ from repro.core.qlm import QLMConfig, QLMController
 from repro.core.request import SLO_CLASSES, make_request
 from repro.core.virtual_queue import VirtualQueue
 from repro.data.workload import SessionSpec, generate_sessions
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import build_registry, calibrate_registry, summarize
 from repro.serving import (AsyncServer, ContinuousBatchingEngine,
                            EngineConfig, FrontendConfig, run_session)
@@ -274,9 +275,10 @@ def main(argv=None) -> dict:
     if args.batch_new_tokens is None:
         args.batch_new_tokens = args.max_new_tokens
 
+    enable_compile_cache()
     key = jax.random.key(args.seed)
     arch_names = [args.arch] + ([args.arch2] if args.arch2 else [])
-    registry = build_registry(arch_names, key)
+    registry = build_registry(arch_names, key, reduced=True)
     ecfg = EngineConfig(max_slots=args.slots, max_seq_len=128,
                         decode_burst=args.decode_burst,
                         attention_backend=args.backend,

@@ -67,6 +67,7 @@ from repro.core.qlm import QLMConfig, QLMController
 from repro.core.request import make_request
 from repro.core.rwt_estimator import HardwareProfile
 from repro.core.virtual_queue import VirtualQueue
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import (ContinuousBatchingEngine, EngineConfig,
                            EngineFailure, FaultPlan, FaultSpec, FaultyEngine)
@@ -607,6 +608,7 @@ def main(argv=None) -> int:
     ap.add_argument("--timeline", default=None,
                     help="write the fault timeline JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.instances is None:
         args.instances = 3 if args.scenario == "combined" else 2
     if args.threaded and args.replay_check:

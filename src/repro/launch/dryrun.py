@@ -35,6 +35,7 @@ from repro.configs import (INPUT_SHAPES, ARCHITECTURES, get_arch, get_shape,
 from repro.configs.base import InputShape, ModelConfig
 from repro.distributed.sharding import (ShardingRules, batch_axes_tree,
                                         build_shardings)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import collective_stats
 from repro.launch.mesh import make_production_mesh
 from repro.models.model_factory import batch_struct, build_model
@@ -203,6 +204,7 @@ def main() -> None:
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     combos = []
     archs = list(ARCHITECTURES) if (args.all or args.arch is None) else [args.arch]

@@ -12,6 +12,7 @@ import dataclasses
 import json
 
 from repro.launch import dryrun
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _report(rec):
@@ -105,6 +106,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", choices=sorted(TARGETS) + ["all"], default="all")
     args = ap.parse_args()
+    enable_compile_cache()
     targets = sorted(TARGETS) if args.target == "all" else [args.target]
     for t in targets:
         arch, shape, gen = TARGETS[t]

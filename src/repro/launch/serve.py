@@ -1,11 +1,16 @@
 """Serving driver: QLM-managed cluster over real JAX engines.
 
-Runs reduced models on CPU with the full QLM stack — request groups,
-virtual queues, RWT estimator, global scheduler, LSO agents — against a
-Poisson workload, and prints SLO attainment / throughput.
+Runs the full QLM stack — request groups, virtual queues, RWT estimator,
+global scheduler, LSO agents — against a Poisson workload, and prints SLO
+attainment / throughput.  By default the model is built at its published
+width with bf16 weights and KV pool (one accelerator's worth);
+``--reduced`` selects the 2-layer float32 smoke config for CPU runs.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
-      --requests 40 --rate 2.0
+  PYTHONPATH=src python -m repro.launch.serve --reduced \
+      --arch granite-3-2b --requests 40 --rate 2.0
+
+Instance i runs on ``jax.devices()[i % len(jax.devices())]`` with its own
+copy of every model's params (its swap registry) and its own KV pool.
 
 Cluster-mode flags (docs/cluster.md):
 
@@ -29,10 +34,12 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
@@ -42,18 +49,31 @@ from repro.core.qlm import QLMConfig, QLMController
 from repro.core.request import make_request
 from repro.core.virtual_queue import VirtualQueue
 from repro.distributed.sharding import ShardingRules, build_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ContinuousBatchingEngine, EngineConfig, ThreadedCluster
 from repro.sim.profiles import calibrate_from_engine
 
 
-def build_registry(arch_names, key):
-    """name -> (Model, params) for each requested arch (reduced configs)."""
+def serving_dtype(reduced: bool):
+    """Weights and KV pool: bf16 at published width, float32 reduced."""
+    return jnp.float32 if reduced else jnp.bfloat16
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_params(model, key, dtype):
+    return model.init(key, dtype)
+
+
+def build_registry(arch_names, key, *, reduced: bool = False):
+    """name -> (Model, params) for each requested arch, at published width
+    (or the reduced smoke config), params on JAX's default device."""
     registry = {}
     for name in arch_names:
-        cfg = get_arch(name).reduced()
-        model = build_model(cfg)
-        registry[name] = (model, model.init(key))
+        cfg = get_arch(name)
+        model = build_model(cfg.reduced() if reduced else cfg)
+        registry[name] = (model, _init_params(model, key,
+                                              serving_dtype(reduced)))
     return registry
 
 
@@ -106,6 +126,7 @@ def calibrate_registry(registry, ecfg: EngineConfig) -> dict:
         eng = ContinuousBatchingEngine(model, params, ecfg, model_name=name)
         hw_by_model[name] = calibrate_from_engine(
             eng, token_capacity=ecfg.resolved_kv_blocks() * ecfg.block_size)
+        eng.release_cache()
     return hw_by_model
 
 
@@ -115,10 +136,13 @@ def build_cluster(args, registry, arch_names):
     Homogeneous: one calibration shared by every instance.  Hetero: one
     calibration per TIER (distinct EngineConfig), so each InstanceInfo
     carries its own per-model profiles and the scheduler's placement is
-    heterogeneity-aware.
+    heterogeneity-aware.  Every calibration runs (and frees its throwaway
+    pool) before the first serving pool is allocated.  Instance i serves
+    from ``jax.devices()[i % n]`` with the registry placed there.
     """
     debug_inv = bool(getattr(args, "debug_invariants", False))
-    base = EngineConfig(max_slots=args.slots, max_seq_len=128,
+    base = EngineConfig(max_slots=args.slots, max_seq_len=args.max_seq_len,
+                        dtype=serving_dtype(args.reduced),
                         decode_burst=args.decode_burst,
                         attention_backend=args.backend,
                         prefix_sharing=args.prefix_sharing,
@@ -126,15 +150,24 @@ def build_cluster(args, registry, arch_names):
     ecfgs = [hetero_engine_cfg(base, i) if args.hetero else base
              for i in range(args.instances)]
     hw_cache = {}
-    engines, agents, infos = [], [], []
-    for i, ecfg in enumerate(ecfgs):
+    for ecfg in ecfgs:
         key = (ecfg.max_slots, ecfg.decode_burst)
         if key not in hw_cache:
             hw_cache[key] = calibrate_registry(registry, ecfg)
-        m0, p0 = registry[arch_names[0]]
+    devices = jax.devices()
+    placed = {}
+    engines, agents, infos = [], [], []
+    for i, ecfg in enumerate(ecfgs):
+        key = (ecfg.max_slots, ecfg.decode_burst)
+        dev = devices[i % len(devices)]
+        if dev not in placed:
+            # device_put aliases params already on dev instead of copying
+            placed[dev] = {name: (model, jax.device_put(params, dev))
+                           for name, (model, params) in registry.items()}
+        m0, p0 = placed[dev][arch_names[0]]
         eng = ContinuousBatchingEngine(m0, p0, ecfg, model_name=arch_names[0])
         vq = VirtualQueue(i)
-        agents.append(QLMAgent(eng, vq, registry))
+        agents.append(QLMAgent(eng, vq, placed[dev]))
         engines.append(eng)
         infos.append(InstanceInfo(i, dict(hw_cache[key]), eng.model_name, vq))
     controller = QLMController(infos, QLMConfig(
@@ -150,8 +183,9 @@ def build_workload(args, arch_names, t_start: float):
     classes = ["interactive", "batch1", "batch2"]
     arrivals = np.cumsum(rng.exponential(1.0 / args.rate, args.requests))
     reqs = []
+    lo, hi = args.prompt_len
     for i in range(args.requests):
-        prompt = rng.integers(0, 100, size=int(rng.integers(4, 24))).tolist()
+        prompt = rng.integers(0, 100, size=int(rng.integers(lo, hi))).tolist()
         r = make_request(prompt, rng.choice(arch_names), rng.choice(classes),
                          arrival_time=t_start + arrivals[i],
                          max_new_tokens=args.max_new_tokens)
@@ -254,16 +288,27 @@ def run_threaded(args, registry, arch_names) -> dict:
     decodes concurrently and the controller ticks on its own thread."""
     engines, agents, infos, controller = build_cluster(args, registry,
                                                        arch_names)
-    cluster = ThreadedCluster(controller, agents, engines)
     t_start = time.monotonic()
     reqs = build_workload(args, arch_names, t_start)
+    return drive_threaded(engines, agents, controller, reqs,
+                          max_wall=args.max_wall)
+
+
+def drive_threaded(engines, agents, controller, reqs, *,
+                   max_wall: float) -> dict:
+    """Serve ``reqs`` open-loop on a ``ThreadedCluster``: submit each at
+    its wall-clock ``arrival_time``, wait (at most ``max_wall`` seconds)
+    until all are terminal, stop the threads — re-raising any error a
+    thread hit — and summarize from the first arrival."""
+    cluster = ThreadedCluster(controller, agents, engines)
+    t_start = min((r.arrival_time for r in reqs), default=time.monotonic())
     cluster.start()
     try:
         for r in reqs:
             time.sleep(max(0.0, r.arrival_time - time.monotonic()))
             controller.submit(r, time.monotonic())
         cluster.wait(lambda: all(_terminal(r) for r in reqs),
-                     timeout=args.max_wall)
+                     timeout=max_wall)
     finally:
         cluster.stop()
     stats = summarize(reqs, controller, engines, t_start, time.monotonic())
@@ -279,9 +324,18 @@ def run_once(args, registry, arch_names) -> dict:
     return run(args, registry, arch_names)
 
 
-def main(argv=None) -> dict:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-layer float32 smoke config of each arch (CPU "
+                         "runs); default is the published width in bf16")
+    ap.add_argument("--max-seq-len", type=int, default=None,
+                    help="per-sequence token limit (default 2048, 128 "
+                         "with --reduced)")
+    ap.add_argument("--prompt-len", type=int, nargs=2, default=(4, 24),
+                    metavar=("MIN", "MAX"),
+                    help="prompt lengths drawn uniformly from [MIN, MAX)")
     ap.add_argument("--arch2", default=None, help="second model for multi-model serving")
     ap.add_argument("--instances", type=int, default=1)
     ap.add_argument("--requests", type=int, default=30)
@@ -324,12 +378,18 @@ def main(argv=None) -> dict:
                     help="run slice AND solver routing same-seed")
     ap.add_argument("--json", default=None, help="write final stats JSON")
     args = ap.parse_args(argv)
+    if args.max_seq_len is None:
+        args.max_seq_len = 128 if args.reduced else 2048
+    return args
 
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    enable_compile_cache()
     key = jax.random.key(args.seed)
 
-    # model registry (reduced configs — same code path as production)
     arch_names = [args.arch] + ([args.arch2] if args.arch2 else [])
-    registry = build_registry(arch_names, key)
+    registry = build_registry(arch_names, key, reduced=args.reduced)
     if args.hetero:
         registry = shard_registry(registry)
 

@@ -404,13 +404,11 @@ def attend_decode(params, cfg, x: jax.Array, lengths: jax.Array,
     # path (slot-validity masking is window-specific).
     if cfg.use_pallas_attention and cfg.sliding_window is None:
         from repro.kernels import ops as kernel_ops
-        from repro.kernels.decode_attention import decode_attention_quant
         q1 = q[:, 0]  # (B, H, D)
         if cfg.kv_quant:
-            interp = jax.default_backend() != "tpu"
-            attn = decode_attention_quant(
+            attn = kernel_ops.decode_attention_quant(
                 q1, new_cache["k"], new_cache["v"], new_cache["k_scale"],
-                new_cache["v_scale"], kv_valid, interpret=interp)
+                new_cache["v_scale"], kv_valid)
         else:
             attn = kernel_ops.decode_attention(q1, new_k, new_v, kv_valid)
         out = attn[:, None].reshape(B, 1, cfg.num_heads * hd)

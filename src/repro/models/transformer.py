@@ -256,6 +256,28 @@ def prefill_chunk(params, cfg, tokens: jax.Array, starts: jax.Array,
     return unembed(params, cfg, x_last), new_cache
 
 
+def _scan_layers_in_place(layer_fn, x, blocks, cache):
+    """Run ``layer_fn(x, block_params, layer_cache) -> (x, layer_cache)``
+    over the layer stack with the stacked page pool in the scan CARRY:
+    each layer's pages are read and written back at their own layer
+    index, so the pool is updated in place.  (Scanning the pool as xs and
+    collecting the new pool as ys holds a second whole pool per step.)"""
+    def body(carry, inp):
+        x, cache = carry
+        bp, layer = inp
+        cl = jax.tree.map(lambda a: a[layer], cache)
+        x, new_cl = layer_fn(x, bp, cl)
+        cache = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            cache, new_cl)
+        return (x, cache), None
+
+    n_layers = jax.tree.leaves(cache)[0].shape[0]
+    (x, cache), _ = jax.lax.scan(body, (x, cache),
+                                 (blocks, jnp.arange(n_layers)))
+    return x, cache
+
+
 def init_paged_cache(cfg, num_blocks: int, block_size: int,
                      dtype=jnp.float32):
     """Stacked per-layer KV page pool: leaves (layers, num_blocks, KVH,
@@ -290,14 +312,10 @@ def prefill_chunk_paged(params, cfg, tokens: jax.Array, starts: jax.Array,
     x = embed_tokens(params, cfg, tokens)
     B, C, _ = x.shape
     positions = starts[:, None] + jnp.arange(C)[None, :]
-
-    def scan_fn(x, inp):
-        bp, cl = inp
-        x, new_cl = _block_prefill_chunk_paged(cfg, x, positions, valid,
-                                               block_table, bp, cl)
-        return x, new_cl
-
-    x, new_cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
+    x, new_cache = _scan_layers_in_place(
+        lambda x, bp, cl: _block_prefill_chunk_paged(
+            cfg, x, positions, valid, block_table, bp, cl),
+        x, params["blocks"], cache)
     x = layers.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = jnp.clip(valid - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
@@ -321,13 +339,10 @@ def decode_step_paged(params, cfg, tokens: jax.Array, lengths: jax.Array,
                       block_table: jax.Array, cache):
     """``decode_step`` against the paged KV pool (block_table: (B, nb))."""
     x = embed_tokens(params, cfg, tokens[:, None])
-
-    def scan_fn(x, inp):
-        bp, cl = inp
-        x, new_cl = _block_decode_paged(cfg, x, lengths, block_table, bp, cl)
-        return x, new_cl
-
-    x, new_cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
+    x, new_cache = _scan_layers_in_place(
+        lambda x, bp, cl: _block_decode_paged(cfg, x, lengths, block_table,
+                                              bp, cl),
+        x, params["blocks"], cache)
     x = layers.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return unembed(params, cfg, x[:, 0]), new_cache
 

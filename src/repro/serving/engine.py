@@ -125,6 +125,14 @@ from repro.serving.kv_cache import BlockManager
 ATTENTION_BACKENDS = ("xla", "pallas", "paged-xla", "paged-pallas")
 
 
+def _single_device(tree) -> Optional[jax.Device]:
+    """The one device holding every array leaf of ``tree``; None when the
+    leaves span several devices (or there are none)."""
+    devices = {d for leaf in jax.tree.leaves(tree)
+               if isinstance(leaf, jax.Array) for d in leaf.devices()}
+    return devices.pop() if len(devices) == 1 else None
+
+
 @dataclasses.dataclass
 class EngineConfig:
     max_slots: int = 8
@@ -226,6 +234,7 @@ class EngineStats:
     decode_time: float = 0.0
     prefill_time: float = 0.0
     swap_time: float = 0.0
+    decode_bursts: int = 0         # fused multi-token decode dispatches
     # prefix sharing (paged backends with EngineConfig.prefix_sharing)
     prefix_lookups: int = 0        # fresh chunked admissions that probed
     prefix_hits: int = 0           # ... and attached a shared chain
@@ -272,6 +281,9 @@ class ContinuousBatchingEngine:
         self.prefix_sharing = bool(cfg.prefix_sharing) and self.paged
         self.model = self._with_backend(model)
         self.params = params
+        # every per-round input is uploaded to the weights' device, so an
+        # engine on chip i never routes its dispatches through chip 0
+        self.device = _single_device(params)
         self.model_name = model_name
         self.stats = EngineStats()
         if self.paged:
@@ -341,12 +353,31 @@ class ContinuousBatchingEngine:
         return model
 
     def _init_cache(self):
-        if self.paged:
-            return self.model.init_paged_cache(
-                self.cfg.resolved_kv_blocks(), self.cfg.block_size,
-                self.cfg.dtype)
-        return self.model.init_cache(self.cfg.max_slots, self.cfg.max_seq_len,
-                                     self.cfg.dtype)
+        # the pool lives on the device that holds the weights, so engines
+        # whose params sit on different chips each fill their own HBM
+        with jax.default_device(self.device):
+            if self.paged:
+                cache = self.model.init_paged_cache(
+                    self.cfg.resolved_kv_blocks(), self.cfg.block_size,
+                    self.cfg.dtype)
+            else:
+                cache = self.model.init_cache(
+                    self.cfg.max_slots, self.cfg.max_seq_len, self.cfg.dtype)
+        return self._put(cache)  # commits it there, no copy
+
+    def _put(self, x) -> jax.Array:
+        """Upload a host array to the weights' device (JAX's default
+        device when the weights span several)."""
+        return jax.device_put(x, self.device)
+
+    def release_cache(self) -> None:
+        """Free the KV pool's device buffers now instead of at garbage
+        collection (a throwaway calibration engine hands its HBM back
+        before the serving engines allocate theirs).  The engine is
+        unusable afterwards."""
+        for leaf in jax.tree.leaves(self.cache):
+            leaf.delete()
+        self.cache = None
 
     def _jit_compute(self) -> None:
         # donate the cache (arg 1) — the page pool is the whole KV budget,
@@ -483,13 +514,13 @@ class ContinuousBatchingEngine:
         BlockManager's incremental table changed since the last dispatch
         (the seed rebuilt + re-uploaded the full table twice per step)."""
         if not self.cfg.incremental_block_table:
-            return jnp.asarray(self._block_table_array())
+            return self._put(self._block_table_array())
         version = self.block_mgr.table_version
         if self._bt_device is None or self._bt_version_seen != version:
-            # .copy(): the manager mutates its table in place and jnp.asarray
+            # .copy(): the manager mutates its table in place and device_put
             # may alias host memory on CPU — the device copy must be a
             # snapshot of THIS version
-            self._bt_device = jnp.asarray(self.block_mgr.slot_table().copy())
+            self._bt_device = self._put(self.block_mgr.slot_table().copy())
             self._bt_version_seen = version
         return self._bt_device
 
@@ -503,9 +534,11 @@ class ContinuousBatchingEngine:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return tok, new_cache
             self._prefill_cache[key] = jax.jit(fn)
-        batch = {"tokens": jnp.asarray(prompt, jnp.int32)[None]}
-        batch.update({k: jnp.asarray(v)[None] for k, v in extras.items()})
-        cache1 = self.model.init_cache(1, self.cfg.max_seq_len, self.cfg.dtype)
+        with jax.default_device(self.device):
+            batch = {"tokens": jnp.asarray(prompt, jnp.int32)[None]}
+            batch.update({k: jnp.asarray(v)[None] for k, v in extras.items()})
+            cache1 = self.model.init_cache(1, self.cfg.max_seq_len,
+                                           self.cfg.dtype)
         tok, cache1 = self._prefill_cache[key](self.params, batch, cache1)
         return int(tok[0]), cache1
 
@@ -528,7 +561,7 @@ class ContinuousBatchingEngine:
 
     def _restore_cache(self, snapshot, b: int) -> None:
         self.cache = jax.tree.map(
-            lambda full, snap: full.at[:, b].set(jnp.asarray(snap)),
+            lambda full, snap: full.at[:, b].set(self._put(snap)),
             self.cache, snapshot)
 
     def _extract_pages(self, block_ids: List[int]):
@@ -551,10 +584,10 @@ class ContinuousBatchingEngine:
         n_snap = jax.tree.leaves(snapshot)[0].shape[1]
         assert len(block_ids) - offset >= n_snap, \
             (len(block_ids), offset, n_snap)
-        ids = jnp.asarray(np.asarray(block_ids[offset:offset + n_snap],  # qlint: disable=host-sync-in-hot-path -- host list -> device upload, no sync
-                                     np.int32))
+        ids = self._put(np.asarray(block_ids[offset:offset + n_snap],  # qlint: disable=host-sync-in-hot-path -- host list -> device upload, no sync
+                                   np.int32))
         self.cache = jax.tree.map(
-            lambda full, snap: full.at[:, ids].set(jnp.asarray(snap)),
+            lambda full, snap: full.at[:, ids].set(self._put(snap)),
             self.cache, snapshot)
 
     def _apply_cow(self) -> None:
@@ -578,10 +611,10 @@ class ContinuousBatchingEngine:
         while width < len(ops):
             width *= 2
         pad = [ops[-1]] * (width - len(ops))
-        src = jnp.asarray(np.asarray([s for s, _ in ops] + [p[0] for p in pad],  # qlint: disable=host-sync-in-hot-path -- host op list -> device upload, no sync
-                                     np.int32))
-        dst = jnp.asarray(np.asarray([d for _, d in ops] + [p[1] for p in pad],  # qlint: disable=host-sync-in-hot-path -- host op list -> device upload, no sync
-                                     np.int32))
+        src = self._put(np.asarray([s for s, _ in ops] + [p[0] for p in pad],  # qlint: disable=host-sync-in-hot-path -- host op list -> device upload, no sync
+                                   np.int32))
+        dst = self._put(np.asarray([d for _, d in ops] + [p[1] for p in pad],  # qlint: disable=host-sync-in-hot-path -- host op list -> device upload, no sync
+                                   np.int32))
         self.cache = self._cow_fn(self.cache, src, dst)
         self.stats.cow_copies += len(ops)
 
@@ -1117,6 +1150,7 @@ class ContinuousBatchingEngine:
         self._materialize_pinned_snapshots()
         self.model = self._with_backend(model)
         self.params = params
+        self.device = _single_device(params)
         self.model_name = model_name
         if self.paged and self.model.init_paged_cache is None:
             raise ValueError(
@@ -1205,13 +1239,13 @@ class ContinuousBatchingEngine:
             # table refreshed AFTER the extends above so it names this
             # chunk's freshly allocated pages
             toks_out, self.cache = self._chunk_fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(starts), jnp.asarray(valid),
+                self.params, self.cache, self._put(tokens),
+                self._put(starts), self._put(valid),
                 self._device_block_table())
         else:
             toks_out, self.cache = self._chunk_fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(starts), jnp.asarray(valid))
+                self.params, self.cache, self._put(tokens),
+                self._put(starts), self._put(valid))
         # sync INSIDE the timed region: np.asarray(toks_out) alone only
         # waits for the token array, leaving the cache update in flight —
         # prefill_time would otherwise time async dispatch, not compute
@@ -1254,13 +1288,13 @@ class ContinuousBatchingEngine:
                 else self.slots[i].prompt_tokens[-1]
         if self.paged:
             next_tokens, self.cache = self._decode_fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self.lengths),
+                self.params, self.cache, self._put(tokens),
+                self._put(self.lengths),
                 self._device_block_table())
         else:
             next_tokens, self.cache = self._decode_fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self.lengths))
+                self.params, self.cache, self._put(tokens),
+                self._put(self.lengths))
         # sync the cache too (see _prefill_chunk_round): decode_time feeds
         # the RWT estimator's decode_per_token via profile()
         jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: one per decode round, feeds decode_time / RWT
@@ -1368,9 +1402,10 @@ class ContinuousBatchingEngine:
             active_mask[i] = True
         bt = self._device_block_table() if self.paged else None
         out, self.cache = self._burst_fn(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(self.lengths), jnp.asarray(remaining),
-            jnp.asarray(active_mask), jnp.int32(n), bt)
+            self.params, self.cache, self._put(tokens),
+            self._put(self.lengths), self._put(remaining),
+            self._put(active_mask), self._put(np.int32(n)), bt)
+        self.stats.decode_bursts += 1
         jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: THE single per-burst host sync the device-resident loop budgets for
         out = np.asarray(out)  # qlint: disable=host-sync-in-hot-path -- the burst's single device->host result copy, inside the timed region
         executed = int((out >= 0).any(axis=1).sum())

@@ -13,13 +13,7 @@ import time
 import jax
 import numpy as np
 import pytest
-
-# Persistent XLA compilation cache: every runner in this module rebuilds
-# engines (per-instance jit caches), so without this the overload
-# comparison measures compilation stalls, not scheduling.
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax-xla-cache-tests")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import ARCHITECTURES
 from repro.core.global_scheduler import InstanceInfo
@@ -29,11 +23,32 @@ from repro.core.request import make_request
 from repro.core.rwt_estimator import HardwareProfile
 from repro.core.virtual_queue import VirtualQueue
 from repro.data.workload import Session
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import (AsyncServer, ContinuousBatchingEngine,
                            EngineConfig, FrontendConfig, run_session)
 
 ARCH = "granite-3-2b"
+
+_CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def compile_cache():
+    """Persistent XLA compilation cache for this module: every runner here
+    rebuilds engines (per-instance jit caches), so without it the overload
+    comparison measures compilation stalls, not scheduling.  Every small
+    program is cached; the previous settings come back afterwards."""
+    saved = {name: getattr(jax.config, name) for name in _CACHE_OPTIONS}
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
