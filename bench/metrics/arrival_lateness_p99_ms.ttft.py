@@ -1,0 +1,8 @@
+"""``arrival_lateness_p99_ms``, read alike for the cells that report ``ttft_p95_s``
+where others report ``interactive_slo_attainment``: a per-layer metric
+moves one end-to-end metric, so the two cells name it apart."""
+from bench.harness.main import read_metric
+
+
+def read(ctx):
+    return read_metric("arrival_lateness_p99_ms", ctx)
