@@ -1,34 +1,13 @@
-"""Engine step-loop benchmark: steps/sec + host-overhead fraction.
+"""Engine prefix-sharing scenario: hit rate and pool blocks saved.
 
-Measures the serving hot loop end-to-end (the thing QLM's RWT math assumes
-runs at hardware speed) across backends x batch sizes x step-loop
-variants:
-
-  * ``seed``            — the pre-optimization loop: single-step dispatch,
-                          no buffer donation, block table rebuilt in
-                          Python and re-uploaded every round;
-  * ``donated``         — buffer donation + incremental block table, still
-                          single-step;
-  * ``burst4/burst16``  — donation + incremental table + fused multi-step
-                          dispatch (``EngineConfig.decode_burst``);
-  * ``burst4_undonated``— burst without donation (isolates the two wins).
-
-Plus a **prefix-sharing scenario** (paged backends): N requests sharing a
-75%-length common prompt prefix served with ``EngineConfig.prefix_sharing``
-on vs off — emitted as ``prefix_sharing`` rows carrying the prefix hit
-rate, the pool blocks saved during the prompt phase (1 shared chain + N
-private tails vs N full chains), and a token-parity bit (the streams must
-be identical in both modes).
-
-Per row: decode ``steps/sec`` over a measured run of ``steps()`` calls,
-the median wall time of the raw jitted dispatch for the same shapes
-(``jit_us_per_iter``), and the derived ``host_overhead_fraction``
-(1 - jit/wall): the share of each iteration spent OUTSIDE the jitted
-computation — np conversions, Python slot bookkeeping, block-table
-management, dispatch latency.  On this CPU container the Pallas backends
-interpret their kernels (wall times are not TPU-representative), but the
-host-overhead fraction and the seed-vs-optimized RATIO are exactly the
-orchestrator costs this benchmark exists to pin down.
+N requests sharing a 75%-length common prompt prefix are served with
+``EngineConfig.prefix_sharing`` on vs off (paged backends), emitted as
+``prefix_sharing`` rows carrying the prefix hit rate, the pool blocks
+saved during the prompt phase (1 shared chain + N private tails vs N full
+chains), COW copies, and a token-parity bit (the streams must be
+identical in both modes).  Everything here is a count, so it holds on any
+backend; host overhead per round is measured on the chip, from the
+program's own spans (``bench/``: ``agent_host_ms_per_round``).
 
 Emits ``BENCH_engine.json``:
 
@@ -41,17 +20,7 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-VARIANTS = (
-    # (label, decode_burst, donate_buffers, incremental_block_table)
-    ("seed", 1, False, False),
-    ("donated", 1, True, True),
-    ("burst4", 4, True, True),
-    ("burst16", 16, True, True),
-    ("burst4_undonated", 4, False, True),
-)
 
 
 def _build(arch, num_layers, d_model):
@@ -63,103 +32,12 @@ def _build(arch, num_layers, d_model):
     return model, params
 
 
-def _mk_engine(model, params, *, backend, batch, burst, donate, incremental,
-               max_seq, prefix_sharing=True):
+def _mk_engine(model, params, *, backend, batch, max_seq, prefix_sharing):
     from repro.serving import ContinuousBatchingEngine, EngineConfig
     cfg = EngineConfig(max_slots=batch, max_seq_len=max_seq, block_size=8,
                        prefill_chunk_tokens=16, attention_backend=backend,
-                       decode_burst=burst, donate_buffers=donate,
-                       incremental_block_table=incremental,
                        prefix_sharing=prefix_sharing)
     return ContinuousBatchingEngine(model, params, cfg, model_name="bench")
-
-
-def _admit_and_drain_prefill(eng, batch, prompt_len, max_new):
-    from repro.core.request import Request
-    rng = np.random.default_rng(7)
-    reqs = [Request(prompt_tokens=rng.integers(0, 100, size=prompt_len).tolist(),
-                    model="bench", slo=1e9, max_new_tokens=max_new)
-            for _ in range(batch)]
-    for r in reqs:
-        assert eng.admit(r)
-    while eng.prefilling_slots():
-        eng.step()
-    return reqs
-
-
-def _probe_jit_us(eng, burst, probes=5):
-    """Median wall microseconds of ONE raw jitted decode dispatch at the
-    engine's current shapes, divided by the burst width — the pure
-    dispatch+compute cost an iteration would have with zero host
-    orchestration.  The probe passes fresh host arrays each call (matching
-    what the step loop uploads) and rebinds the donated cache."""
-    B = eng.cfg.max_slots
-    tokens = np.zeros(B, np.int32)
-    for i in eng.decode_slots():
-        r = eng.slots[i]
-        tokens[i] = r.output_tokens[-1] if r.output_tokens \
-            else r.prompt_tokens[-1]
-    active = np.zeros(B, bool)
-    active[eng.decode_slots()] = True
-    remaining = np.full(B, 1_000_000, np.int32)  # never finishes mid-probe
-    samples = []
-    for _ in range(probes + 1):  # first call warms any residual compile
-        t0 = time.perf_counter()
-        if burst > 1:
-            bt = eng._device_block_table() if eng.paged else None
-            out, eng.cache = eng._burst_fn(
-                eng.params, eng.cache, jnp.asarray(tokens),
-                jnp.asarray(eng.lengths), jnp.asarray(remaining),
-                jnp.asarray(active), jnp.int32(burst), bt)
-            jax.block_until_ready((out, eng.cache))
-        else:
-            if eng.paged:
-                nxt, eng.cache = eng._decode_fn(
-                    eng.params, eng.cache, jnp.asarray(tokens),
-                    jnp.asarray(eng.lengths), eng._device_block_table())
-            else:
-                nxt, eng.cache = eng._decode_fn(
-                    eng.params, eng.cache, jnp.asarray(tokens),
-                    jnp.asarray(eng.lengths))
-            jax.block_until_ready((nxt, eng.cache))
-        samples.append(time.perf_counter() - t0)
-    return float(np.median(samples[1:])) * 1e6 / burst
-
-
-def bench_variant(model, params, *, backend, batch, label, burst, donate,
-                  incremental, iters, max_seq):
-    prompt_len = 16
-    eng = _mk_engine(model, params, backend=backend, batch=batch,
-                     burst=burst, donate=donate, incremental=incremental,
-                     max_seq=max_seq)
-    # max_new sized so no request retires during warmup + measurement
-    reqs = _admit_and_drain_prefill(eng, batch, prompt_len,
-                                    max_new=iters + 4 * burst + 8)
-    eng.steps()  # warm the decode/burst jit before timing
-
-    it0 = eng.stats.decode_iterations
-    tok0 = eng.stats.tokens_generated
-    t0 = time.perf_counter()
-    while eng.stats.decode_iterations - it0 < iters:
-        eng.steps()
-    wall = time.perf_counter() - t0
-    n_iters = eng.stats.decode_iterations - it0
-    n_tokens = eng.stats.tokens_generated - tok0
-    assert all(not r.finished() for r in reqs), \
-        "requests retired mid-measurement (grow max_new / max_seq)"
-
-    wall_us = wall * 1e6 / n_iters
-    jit_us = _probe_jit_us(eng, burst)
-    return {
-        "backend": backend, "batch": batch, "variant": label,
-        "decode_burst": burst, "donated": donate,
-        "incremental_table": incremental,
-        "steps_per_sec": round(n_iters / wall, 2),
-        "tokens_per_sec": round(n_tokens / wall, 2),
-        "wall_us_per_iter": round(wall_us, 1),
-        "jit_us_per_iter": round(jit_us, 1),
-        "host_overhead_fraction": round(max(0.0, 1.0 - jit_us / wall_us), 4),
-    }
 
 
 def bench_prefix_sharing(model, params, *, backend, batch=8, prompt_len=32,
@@ -177,7 +55,6 @@ def bench_prefix_sharing(model, params, *, backend, batch=8, prompt_len=32,
 
     def serve(sharing):
         eng = _mk_engine(model, params, backend=backend, batch=batch,
-                         burst=1, donate=True, incremental=True,
                          max_seq=prompt_len + max_new + 8,
                          prefix_sharing=sharing)
         reqs = [Request(prompt_tokens=p, model="bench", slo=1e9,
@@ -219,43 +96,20 @@ def bench_prefix_sharing(model, params, *, backend, batch=8, prompt_len=32,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="small CI sweep (xla + paged-pallas at batch 4)")
+                    help="small CI run (paged-pallas only)")
     ap.add_argument("--out", default="BENCH_engine.json")
-    ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args()
 
     if args.smoke:
-        backends = ["xla", "paged-pallas"]
-        batches = [4]
         num_layers, d_model = 1, 64
-        iters = args.iters or 16
-        variants = [v for v in VARIANTS if v[0] != "burst16"]
         sharing_backends = ["paged-pallas"]
     else:
-        backends = ["xla", "pallas", "paged-xla", "paged-pallas"]
-        batches = [1, 4, 8]
         num_layers, d_model = 2, 128
-        iters = args.iters or 32
-        variants = list(VARIANTS)
         sharing_backends = ["paged-xla", "paged-pallas"]
 
     model, params = _build("granite-3-2b", num_layers, d_model)
-    max_seq = 16 + iters + 16 * 4 + 32  # prompt + run + burst slack
 
     t_start = time.time()
-    rows = []
-    for backend in backends:
-        for batch in batches:
-            for label, burst, donate, incremental in variants:
-                row = bench_variant(model, params, backend=backend,
-                                    batch=batch, label=label, burst=burst,
-                                    donate=donate, incremental=incremental,
-                                    iters=iters, max_seq=max_seq)
-                rows.append(row)
-                print(f"{backend:>12} b={batch} {label:>16}: "
-                      f"{row['steps_per_sec']:>8.1f} steps/s  "
-                      f"host-overhead {row['host_overhead_fraction']:.0%}")
-
     # shared-prompt scenario (paged backends; 8 x 75%-shared prefixes)
     sharing_rows = []
     for backend in sharing_backends:
@@ -268,49 +122,19 @@ def main() -> None:
               f"(saved {row['blocks_saved']}), tokens_match="
               f"{row['tokens_match']}")
 
-    # seed-vs-optimized summary per (backend, batch)
-    summary = []
-    for backend in backends:
-        for batch in batches:
-            by = {r["variant"]: r for r in rows
-                  if r["backend"] == backend and r["batch"] == batch}
-            seed, burst = by.get("seed"), by.get("burst4")
-            if seed and burst:
-                summary.append({
-                    "backend": backend, "batch": batch,
-                    "burst4_speedup_vs_seed": round(
-                        burst["steps_per_sec"] / seed["steps_per_sec"], 3),
-                    "host_overhead_seed": seed["host_overhead_fraction"],
-                    "host_overhead_burst4": burst["host_overhead_fraction"],
-                })
-
     result = {
         "meta": {
             "backend": jax.default_backend(),
-            "pallas_interpret": jax.default_backend() != "tpu",
             "model": {"arch": "granite-3-2b-reduced",
                       "num_layers": num_layers, "d_model": d_model},
-            "iters": iters,
-            "note": ("steps/sec at reduced scale; Pallas kernels interpret "
-                     "off-TPU so absolute wall times are not "
-                     "TPU-representative — the seed-vs-optimized ratio and "
-                     "host_overhead_fraction are the orchestrator metrics "
-                     "this file tracks per PR"),
             "wall_seconds": 0.0,
         },
-        "engine": rows,
         "prefix_sharing": sharing_rows,
-        "summary": summary,
     }
     result["meta"]["wall_seconds"] = round(time.time() - t_start, 1)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=2)
     print(f"wrote {args.out} ({result['meta']['wall_seconds']}s)")
-    for s in summary:
-        print(f"{s['backend']:>12} b={s['batch']}: burst4 "
-              f"{s['burst4_speedup_vs_seed']}x vs seed, host overhead "
-              f"{s['host_overhead_seed']:.0%} -> "
-              f"{s['host_overhead_burst4']:.0%}")
 
 
 if __name__ == "__main__":

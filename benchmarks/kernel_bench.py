@@ -1,17 +1,14 @@
-"""Paged chunk-attention kernel microbenchmark: gather path vs fused.
+"""Paged chunk-attention kernel model: gather path vs fused.
 
-Measures, per (prefix_len, block_size) point:
-
-  * wall time of the XLA gather path (densify the pre-chunk page pool
-    through the block table + two-segment masked softmax — exactly what
-    ``attend_prefill_chunk_paged`` falls back to), and of the fused Pallas
-    paged prefill-chunk kernel (``kernels/paged_prefill_attention.py``);
-  * MODELED per-chunk HBM bytes for both: the gather path moves the whole
-    padded pool slice three times (pool read -> densified write -> attention
-    read), the fused kernel streams only the live pages once, in place.
-    The model is the roofline metric here — on this CPU container the
-    Pallas kernel executes in interpret mode (Python), so its wall time is
-    NOT meaningful; on TPU the same call sites compile via Mosaic.
+Computes, per (prefix_len, block_size) point, the MODELED per-chunk HBM
+bytes of the XLA gather path (densify the pre-chunk page pool through the
+block table + two-segment masked softmax — exactly what
+``attend_prefill_chunk_paged`` falls back to) and of the fused Pallas
+paged prefill-chunk kernel (``kernels/paged_prefill_attention.py``): the
+gather path moves the whole padded pool slice three times (pool read ->
+densified write -> attention read), the fused kernel streams only the
+live pages once, in place.  Kernel times come from the chip's profiler
+trace (``bench/``), never from here.
 
 Also sweeps the paged decode kernel's multi-page kv tiles
 (``pages_per_tile``) across block sizes.
@@ -73,37 +70,14 @@ def modeled_chunk_hbm_bytes(*, prefix: int, table_tokens: int, bs: int,
 
 
 def bench_prefill_chunk(prefixes, block_sizes, *, chunk, num_q_heads,
-                        kv_heads, head_dim, iters, time_fused):
-    from repro.kernels import ops, ref
+                        kv_heads, head_dim):
     from repro.kernels.paged_decode_attention import auto_pages_per_tile
 
-    gather_fn = jax.jit(ref.paged_prefill_attention_ref)
     rows = []
-    rng = np.random.default_rng(0)
     for bs in block_sizes:
         for prefix in prefixes:
             nb = math.ceil((prefix + chunk) / bs)   # table covers the prompt
-            N = nb + 8
-            q = rng.standard_normal(
-                (1, num_q_heads, chunk, head_dim)).astype(np.float32)
-            kp = rng.standard_normal(
-                (N, kv_heads, bs, head_dim)).astype(np.float32)
-            vp = rng.standard_normal(
-                (N, kv_heads, bs, head_dim)).astype(np.float32)
-            ck = rng.standard_normal(
-                (1, kv_heads, chunk, head_dim)).astype(np.float32)
-            cv = rng.standard_normal(
-                (1, kv_heads, chunk, head_dim)).astype(np.float32)
-            bt = rng.permutation(N)[:nb].reshape(1, nb).astype(np.int32)
-            starts = np.array([prefix], np.int32)
-            valid = np.array([chunk], np.int32)
-            args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                    jnp.asarray(ck), jnp.asarray(cv), jnp.asarray(bt),
-                    jnp.asarray(starts), jnp.asarray(valid))
             P = auto_pages_per_tile(bs, nb)
-            gather_us = _time_call(gather_fn, *args, iters=iters) * 1e6
-            fused_us = (_time_call(ops.paged_prefill_attention, *args,
-                                   iters=iters) * 1e6 if time_fused else None)
             model = dict(prefix=prefix, table_tokens=nb * bs, bs=bs,
                          chunk=chunk, num_q_heads=num_q_heads,
                          kv_heads=kv_heads, head_dim=head_dim, itemsize=4,
@@ -113,8 +87,6 @@ def bench_prefill_chunk(prefixes, block_sizes, *, chunk, num_q_heads,
             rows.append({
                 "prefix": prefix, "block_size": bs, "chunk": chunk,
                 "pages_per_tile": P,
-                "gather_us": round(gather_us, 1),
-                "fused_us": None if fused_us is None else round(fused_us, 1),
                 "gather_hbm_bytes": g_bytes,
                 "fused_hbm_bytes": f_bytes,
                 "hbm_bytes_saved": g_bytes - f_bytes,
@@ -211,13 +183,12 @@ def main() -> None:
             "backend": jax.default_backend(),
             "pallas_interpret": not on_tpu,
             "shape": shape,
-            "note": ("fused wall times run the Pallas kernel in interpret "
-                     "mode off-TPU (Python per grid step — not a perf "
-                     "number); gather/fused modeled HBM bytes are the "
-                     "roofline comparison and hold on any backend"),
+            "note": ("gather/fused modeled HBM bytes hold on any "
+                     "backend; decode tile wall times run the Pallas "
+                     "kernel in interpret mode off-TPU (Python per grid "
+                     "step — not a perf number)"),
         },
-        "prefill_chunk": bench_prefill_chunk(
-            prefixes, block_sizes, iters=iters, time_fused=True, **shape),
+        "prefill_chunk": bench_prefill_chunk(prefixes, block_sizes, **shape),
         "prefill_total": cumulative_prefill(prompt_lens, block_sizes, **shape),
         "decode_tiles": bench_decode_tiles(
             block_sizes, kv_tokens=2048 if args.smoke else 4096,

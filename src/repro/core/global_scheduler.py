@@ -15,6 +15,7 @@ from repro.core.request_group import RequestGroup
 from repro.core.rwt_estimator import HardwareProfile, RWTEstimator
 from repro.core.solver import GroupSpec, InstanceSpec, Solution, solve
 from repro.core.virtual_queue import VirtualQueue
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -78,10 +79,11 @@ class GlobalScheduler:
         """
         self.invocations += 1
         live = [g for g in groups if not g.done()]
-        gspecs, ispecs = self.build_specs(live, instances, now)
-        sol = solve(gspecs, ispecs, exact_threshold=self.exact_threshold,
-                    seed=self.seed + self.invocations,
-                    objective=self.objective)
+        with span("qlm.scheduler.solve"):
+            gspecs, ispecs = self.build_specs(live, instances, now)
+            sol = solve(gspecs, ispecs, exact_threshold=self.exact_threshold,
+                        seed=self.seed + self.invocations,
+                        objective=self.objective)
         if not sol.feasible:
             self._edf_fallback(live, instances)
             return sol
@@ -117,7 +119,8 @@ class GlobalScheduler:
         """Walk each VQ accumulating RWT drain estimates; violation iff some
         group's predicted completion exceeds its deadline slack (§4
         "Handling New Incoming Requests")."""
-        return bool(self.violations(instances, now))
+        with span("qlm.scheduler.predict"):
+            return bool(self.violations(instances, now))
 
     def violations(self, instances: Sequence[InstanceInfo], now: float,
                    slo_ceiling: Optional[float] = None,

@@ -19,6 +19,7 @@ from typing import Callable, Dict, Optional, Tuple
 from repro.core.request import Request
 from repro.core.virtual_queue import VirtualQueue
 from repro.serving.engine import ContinuousBatchingEngine
+from repro.spans import span
 
 
 class QLMAgent:
@@ -46,7 +47,7 @@ class QLMAgent:
 
     # -- request pulling LSO ------------------------------------------------
     def _pull(self) -> Optional[Request]:
-        with self.queue_lock:
+        with span("qlm.lso.pull"), self.queue_lock:
             pushed = self.engine.take_pushback()
             if pushed is not None:
                 pushed._in_flight = False
@@ -66,7 +67,7 @@ class QLMAgent:
     # -- eviction + swap LSOs -------------------------------------------------
     def sync(self) -> None:
         """Reconcile engine state with the (possibly re-ordered) VQ."""
-        with self.queue_lock:
+        with span("qlm.lso.sync"), self.queue_lock:
             self._sync_locked()
 
     def _sync_locked(self) -> None:
@@ -76,7 +77,8 @@ class QLMAgent:
         # model swapping: head group's model must be resident
         if self.enable_swap and head.model != self.engine.model_name:
             model, params = self.registry[head.model]
-            evicted = self.engine.swap_model(model, params, head.model)
+            with span("qlm.lso.swap"):
+                evicted = self.engine.swap_model(model, params, head.model)
             for r in evicted:
                 r._in_flight = False
                 r._served_by = None
@@ -93,14 +95,16 @@ class QLMAgent:
                             if not getattr(r, "_in_flight", False)]
             if head_pending and not any(
                     self.engine.can_admit(r) for r in head_pending):
-                for slot in list(self.engine.active_slots()):
-                    running = self.engine.slots[slot]
-                    if running is not None and running.group_id != head.group_id:
-                        r = self.engine.evict_slot(slot)
-                        r._in_flight = False
-                        r._served_by = None
-                        if self.engine.can_admit(head_pending[0]):
-                            break
+                with span("qlm.lso.evict"):
+                    for slot in list(self.engine.active_slots()):
+                        running = self.engine.slots[slot]
+                        if running is not None \
+                                and running.group_id != head.group_id:
+                            r = self.engine.evict_slot(slot)
+                            r._in_flight = False
+                            r._served_by = None
+                            if self.engine.can_admit(head_pending[0]):
+                                break
 
     def reset(self) -> None:
         """Failure-path reset (engine crash / recovery / external engine
@@ -127,6 +131,7 @@ class QLMAgent:
         middle of a dispatch, and because those sites only try-lock,
         holding it for the full quantum is deadlock-free."""
         lock = getattr(self.engine, "lock", None)
-        with lock if lock is not None else contextlib.nullcontext():
+        with span("qlm.agent.iteration"), \
+                lock if lock is not None else contextlib.nullcontext():
             self.sync()
             return self.engine.steps()

@@ -11,7 +11,6 @@ import contextlib
 import dataclasses
 import functools
 import math
-import threading
 from typing import Callable, List, Optional, Sequence
 
 from repro.core import routing
@@ -20,6 +19,7 @@ from repro.core.request import Request
 from repro.core.request_group import (RequestGroup, classify_into_groups,
                                       create_request_groups)
 from repro.core.rwt_estimator import RWTEstimator
+from repro.spans import TimedRLock, span
 
 
 @dataclasses.dataclass
@@ -169,7 +169,9 @@ class QLMController:
         # ``QLMAgent.queue_lock``), so FCFS pops and ``not_before``
         # redelivery gates stay race-free.  Reentrant: entry points
         # compose.  Single-threaded drivers pay one uncontended acquire.
-        self.lock = threading.RLock()
+        # A blocking acquire is a ``qlm.lock_wait`` span: the time an
+        # agent, and with it the chip, waits here is on the trace.
+        self.lock = TimedRLock()
         self.instances = list(instances)
         self.estimator = RWTEstimator(self.cfg.z_conservative)
         self.scheduler = GlobalScheduler(self.estimator, seed=seed)
@@ -823,29 +825,34 @@ class QLMController:
         serve path (one bad request must not kill the loop) or letting
         ``predict_violation`` report an unfixable violation every
         cooldown tick (solver thrash)."""
-        if not self.can_serve(req.model):
-            self.record_rejection(req, now)
-            return False
-        self.global_queue.append(req)
-        g = classify_into_groups(req, self.groups, max_group=self.max_group)
-        if g is None:
-            g = RequestGroup(model=req.model, slo=req.slo)
-            g.add(req)
-            self.groups.append(g)
-            self._place_new_group(g, now)
-        elif not self._placed(g):
-            # liveness: the group existed but is reachable from no instance
-            # (an infeasible-solve set_order/_edf_fallback dropped it, or a
-            # VQ popped it while momentarily done) — without re-placement
-            # the new request would strand in the global queue until an
-            # unrelated violation triggers a full reschedule
-            self._place_new_group(g, now)
-        if self.cfg.reschedule_on_arrival and \
-                now - self._last_reschedule >= self.cfg.reschedule_cooldown and \
-                self.scheduler.predict_violation(self.schedulable_instances(),
-                                                 now):
-            self.reschedule(now)
-        return True
+        with span("qlm.controller.submit", req_id=req.req_id):
+            if not self.can_serve(req.model):
+                self.record_rejection(req, now)
+                return False
+            with span("qlm.controller.place"):
+                self.global_queue.append(req)
+                g = classify_into_groups(req, self.groups,
+                                         max_group=self.max_group)
+                if g is None:
+                    g = RequestGroup(model=req.model, slo=req.slo)
+                    g.add(req)
+                    self.groups.append(g)
+                    self._place_new_group(g, now)
+                elif not self._placed(g):
+                    # liveness: the group existed but is reachable from no
+                    # instance (an infeasible-solve set_order/_edf_fallback
+                    # dropped it, or a VQ popped it while momentarily
+                    # done) — without re-placement the new request would
+                    # strand in the global queue until an unrelated
+                    # violation triggers a full reschedule
+                    self._place_new_group(g, now)
+            if self.cfg.reschedule_on_arrival and \
+                    now - self._last_reschedule \
+                    >= self.cfg.reschedule_cooldown and \
+                    self.scheduler.predict_violation(
+                        self.schedulable_instances(), now):
+                self.reschedule(now)
+            return True
 
     @_locked
     def submit_batch(self, requests: Sequence[Request], now: float) -> None:
@@ -904,13 +911,14 @@ class QLMController:
         were emptied when the instance departed and must stay empty, and
         a draining instance is departing capacity the solver must not
         count on."""
-        self.gc_groups()
-        self._last_reschedule = now
-        if self.cfg.routing == "slice":
-            self.routing_invocations += 1
-            return routing.slice_schedule(self, now)
-        return self.scheduler.schedule(self.groups,
-                                       self.schedulable_instances(), now)
+        with span("qlm.controller.reschedule"):
+            self.gc_groups()
+            self._last_reschedule = now
+            if self.cfg.routing == "slice":
+                self.routing_invocations += 1
+                return routing.slice_schedule(self, now)
+            return self.scheduler.schedule(self.groups,
+                                           self.schedulable_instances(), now)
 
     @_locked
     def tick(self, now: float) -> bool:
@@ -922,21 +930,22 @@ class QLMController:
         group heads, firing the agents' head-change eviction LSO) without
         any new information to act on.
         """
-        self.check_watchdog(now)
-        self.check_heartbeats(now)
-        self._retry_deferred(now)
-        self._finish_drains(now)
-        self.migration_sweep(now)
-        if now - self._last_reschedule < self.cfg.reschedule_cooldown:
-            self._check_invariants()
-            return False
-        rescheduled = False
-        if self.scheduler.predict_violation(self.schedulable_instances(),
-                                            now):
-            self.reschedule(now)
-            rescheduled = True
-        self._check_invariants()
-        return rescheduled
+        with span("qlm.controller.tick"):
+            with span("qlm.controller.sweep"):
+                self.check_watchdog(now)
+                self.check_heartbeats(now)
+                self._retry_deferred(now)
+                self._finish_drains(now)
+                self.migration_sweep(now)
+            rescheduled = False
+            if now - self._last_reschedule >= self.cfg.reschedule_cooldown \
+                    and self.scheduler.predict_violation(
+                        self.schedulable_instances(), now):
+                self.reschedule(now)
+                rescheduled = True
+            with span("qlm.controller.invariants"):
+                self._check_invariants()
+            return rescheduled
 
     _inv_sampler = None
 

@@ -38,6 +38,9 @@ class Request:
 
     # lifecycle (filled by the runtime / simulator)
     group_id: Optional[int] = None
+    # when an engine first put the request in a slot, on that engine's
+    # lifecycle clock; eviction, resume, restart and migration keep it
+    admitted_time: Optional[float] = None
     first_token_time: Optional[float] = None
     completion_time: Optional[float] = None
     output_tokens: List[int] = dataclasses.field(default_factory=list)

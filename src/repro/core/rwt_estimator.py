@@ -72,8 +72,8 @@ class HardwareProfile:
     # overhead below amortizes across the burst instead of being charged
     # per token.
     decode_burst: int = 1
-    # Host + dispatch seconds per fused decode dispatch (the
-    # host_overhead_fraction engine_bench.py measures, in absolute terms).
+    # Host + dispatch seconds per fused decode dispatch (what the
+    # benchmark's agent_host_ms_per_round measures on the chip).
     # 0 folds it into decode_per_token (the pre-burst reading).
     dispatch_overhead: float = 0.0
 

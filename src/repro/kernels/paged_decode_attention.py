@@ -247,6 +247,8 @@ def _decode_call(q, k_pages, v_pages, block_table, lengths, scale_pages, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=("paged_decode_attention_quant" if quant
+              else "paged_decode_attention"),
     )(bt, lengths.astype(jnp.int32), *inputs)
     return out.reshape(B, H, D)
 

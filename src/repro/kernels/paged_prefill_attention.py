@@ -256,6 +256,8 @@ def _prefill_call(q, k_pages, v_pages, chunk_k, chunk_v, block_table,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=("paged_prefill_attention_quant" if quant
+              else "paged_prefill_attention"),
     )(bt, starts.astype(jnp.int32), valid.astype(jnp.int32), *inputs)
     return out.reshape(B, H, C, D)
 

@@ -39,6 +39,7 @@ import time
 from typing import Callable, List, Optional
 
 from repro.serving.faults import EngineFailure
+from repro.spans import GCSpans, span
 
 
 class ThreadedCluster:
@@ -77,6 +78,7 @@ class ThreadedCluster:
         # chaos drains an instance at the exact round its target holds
         # co-resident sharers, which a polling loop would miss.
         self.round_hook: Optional[Callable[[int], None]] = None
+        self._gc_spans = GCSpans()
         for agent in self.agents:
             agent.queue_lock = controller.lock
 
@@ -85,6 +87,7 @@ class ThreadedCluster:
         if self._threads:
             raise RuntimeError("cluster already started")
         self._stop.clear()
+        self._gc_spans.install()
         for idx in range(len(self.agents)):
             t = threading.Thread(target=self._agent_loop, args=(idx,),
                                  name=f"qlm-agent-{idx}", daemon=True)
@@ -102,6 +105,7 @@ class ThreadedCluster:
         for t in self._threads + ([self._tick_thread]
                                   if self._tick_thread else []):
             t.join(max(0.0, deadline - time.monotonic()))
+        self._gc_spans.remove()
         alive = [t.name for t in self._threads if t.is_alive()]
         self._threads = []
         self._tick_thread = None
@@ -147,34 +151,38 @@ class ThreadedCluster:
                 # departed slot: park cheaply until replaced or stopped
                 self._stop.wait(self.idle_sleep * 10)
                 continue
-            agent = self.agents[idx]
-            try:
-                agent.run_iteration()
-            except EngineFailure as e:
-                self.failures[idx] += 1
-                ctl.report_engine_failure(idx, e, self.clock(),
-                                          engine=agent.engine)
-                agent.reset()
-                continue
-            except BaseException as e:  # noqa: BLE001 — surfaced via stop()
-                self.errors.append(e)
-                return
-            with ctl.lock:
-                # swap/drain estimates read instances[].current_model; the
-                # round-robin drivers refresh it every round, threaded
-                # agents must too (a live swap lands mid-traffic here)
-                ctl.instances[idx].current_model = agent.engine.model_name
-                ctl.heartbeat(idx, self.clock())
-            self.rounds[idx] += 1
-            hook = self.round_hook
-            if hook is not None:
+            with span("qlm.agent.loop"):
+                agent = self.agents[idx]
                 try:
-                    hook(idx)
+                    agent.run_iteration()
+                except EngineFailure as e:
+                    self.failures[idx] += 1
+                    ctl.report_engine_failure(idx, e, self.clock(),
+                                              engine=agent.engine)
+                    agent.reset()
+                    continue
                 except BaseException as e:  # noqa: BLE001 — surfaced via stop()
                     self.errors.append(e)
                     return
-            if self._idle(idx, agent):
-                time.sleep(self.idle_sleep)
+                with span("qlm.agent.heartbeat"), ctl.lock:
+                    # swap/drain estimates read instances[].current_model;
+                    # the round-robin drivers refresh it every round,
+                    # threaded agents must too (a live swap lands
+                    # mid-traffic here)
+                    ctl.instances[idx].current_model = agent.engine.model_name
+                    ctl.heartbeat(idx, self.clock())
+                self.rounds[idx] += 1
+                hook = self.round_hook
+                if hook is not None:
+                    try:
+                        with span("qlm.agent.hook"):
+                            hook(idx)
+                    except BaseException as e:  # noqa: BLE001 — surfaced via stop()
+                        self.errors.append(e)
+                        return
+                if self._idle(idx, agent):
+                    with span("qlm.agent.idle"):
+                        time.sleep(self.idle_sleep)
 
     def _idle(self, idx: int, agent) -> bool:
         """No residents and nothing pullable: back off instead of

@@ -121,6 +121,7 @@ import numpy as np
 from repro.core.request import Request
 from repro.models.model_factory import Model
 from repro.serving.kv_cache import BlockManager
+from repro.spans import span
 
 ATTENTION_BACKENDS = ("xla", "pallas", "paged-xla", "paged-pallas")
 
@@ -166,7 +167,7 @@ class EngineConfig:
     decode_burst: int = 1
     # Donate the KV cache (and decode token array) into the jitted decode /
     # chunk calls so XLA updates the pool in place instead of copying it
-    # every iteration.  Off only for A/B benchmarking (engine_bench.py).
+    # every iteration.  Off only for A/B comparisons (tests).
     donate_buffers: bool = True
     # Maintain the (max_slots, max_blocks_per_seq) block table incrementally
     # inside BlockManager (refreshing the device copy only when it changed)
@@ -745,6 +746,7 @@ class ContinuousBatchingEngine:
         if slot is None or not self.can_admit(req):
             return False
         t0 = self._wall()
+        admitted = self.clock()
         ex = extras or req.extras or {}
         my_layout = "paged" if self.paged else "dense"
         if req.snapshot is not None \
@@ -881,6 +883,8 @@ class ContinuousBatchingEngine:
             n0 = len(self._admit_completed)
             self._finish_if_done(slot, tok, now, self._admit_completed)
             self.completed.extend(self._admit_completed[n0:])
+        if req.admitted_time is None:
+            req.admitted_time = admitted
         self.stats.prefill_time += self._wall() - t0
         return True
 
@@ -1202,128 +1206,148 @@ class ContinuousBatchingEngine:
         work = self.prefilling_slots()
         if not work:
             return
-        t0 = self._wall()
-        C = self._chunk_quantum()
-        chunks: Dict[int, Tuple[np.ndarray, int, bool]] = {}
-        for i in work:
-            req = self.slots[i]
-            pos = int(self.prefill_pos[i])
-            n = min(C, req.prompt_len - pos)
-            final = pos + n >= req.prompt_len
-            # chunk-granular KV growth (+1 slot for the first decode token
-            # on the final chunk, mirroring single-shot accounting)
-            need = req.prompt_len + 1 if final else pos + n
-            if not self.block_mgr.extend(req.req_id, need):
-                # mid-prefill OOM: preempt; the snapshot keeps chunk progress
-                # and the request becomes re-pullable (sim _evict_seq parity)
-                self.stats.preemptions += 1
-                self.evict_slot(i)
-                req._in_flight = False
-                continue
-            chunk = np.asarray(req.prompt_tokens[pos:pos + n], np.int32)  # qlint: disable=host-sync-in-hot-path -- host prompt slice -> chunk array, no device sync
-            chunks[i] = (chunk, n, final)
-        if not chunks:
-            return
-        # COW copies from the extends above (shared partial tails) must
-        # land before this dispatch writes the destination pages
-        self._apply_cow()
-        bucket = self._bucket_for(max(n for _, n, _ in chunks.values()))
-        tokens = np.zeros((self.cfg.max_slots, bucket), np.int32)
-        starts = np.zeros(self.cfg.max_slots, np.int32)
-        valid = np.zeros(self.cfg.max_slots, np.int32)
-        for i, (chunk, n, _) in chunks.items():
-            tokens[i, :n] = chunk
-            starts[i] = self.prefill_pos[i]
-            valid[i] = n
-        if self.paged:
-            # table refreshed AFTER the extends above so it names this
-            # chunk's freshly allocated pages
-            toks_out, self.cache = self._chunk_fn(
-                self.params, self.cache, self._put(tokens),
-                self._put(starts), self._put(valid),
-                self._device_block_table())
-        else:
-            toks_out, self.cache = self._chunk_fn(
-                self.params, self.cache, self._put(tokens),
-                self._put(starts), self._put(valid))
-        # sync INSIDE the timed region: np.asarray(toks_out) alone only
-        # waits for the token array, leaving the cache update in flight —
-        # prefill_time would otherwise time async dispatch, not compute
-        # (and RWT calibration via profile() would under-report)
-        jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: one per chunk round, feeds prefill_time / RWT calibration
-        toks_out = np.asarray(toks_out)  # qlint: disable=host-sync-in-hot-path -- the round's single device->host result copy, inside the timed region
-        self.stats.prefill_chunks += 1
-        now = self.clock()
-        for i, (_, n, final) in chunks.items():
-            req = self.slots[i]
-            self.prefill_pos[i] += n
-            self.lengths[i] = self.prefill_pos[i]
-            if self.prefix_sharing:
-                # publish the prompt blocks this chunk completed: later
-                # admissions with the same leading tokens attach to these
-                # pages instead of re-prefilling them
-                self.block_mgr.register_prefix(
-                    req.req_id, req.prompt_tokens, int(self.prefill_pos[i]))
-            if final:
-                tok = int(toks_out[i])
-                if req.first_token_time is None:
-                    req.first_token_time = now
-                req.output_tokens.append(tok)
-                req.generated += 1
-                self.stats.prefills += 1
-                self._finish_if_done(i, tok, now, done)
-        self.stats.prefill_time += self._wall() - t0
+        with span("qlm.engine.prefill", slots=len(work)):
+            with span("qlm.engine.prep"):
+                t0 = self._wall()
+                C = self._chunk_quantum()
+                chunks: Dict[int, Tuple[np.ndarray, int, bool]] = {}
+                for i in work:
+                    req = self.slots[i]
+                    pos = int(self.prefill_pos[i])
+                    n = min(C, req.prompt_len - pos)
+                    final = pos + n >= req.prompt_len
+                    # chunk-granular KV growth (+1 slot for the first
+                    # decode token on the final chunk, mirroring
+                    # single-shot accounting)
+                    need = req.prompt_len + 1 if final else pos + n
+                    if not self.block_mgr.extend(req.req_id, need):
+                        # mid-prefill OOM: preempt; the snapshot keeps
+                        # chunk progress and the request becomes
+                        # re-pullable (sim _evict_seq parity)
+                        self.stats.preemptions += 1
+                        self.evict_slot(i)
+                        req._in_flight = False
+                        continue
+                    chunk = np.asarray(req.prompt_tokens[pos:pos + n], np.int32)  # qlint: disable=host-sync-in-hot-path -- host prompt slice -> chunk array, no device sync
+                    chunks[i] = (chunk, n, final)
+                if not chunks:
+                    return
+                # COW copies from the extends above (shared partial
+                # tails) must land before this dispatch writes the
+                # destination pages
+                self._apply_cow()
+                bucket = self._bucket_for(
+                    max(n for _, n, _ in chunks.values()))
+                tokens = np.zeros((self.cfg.max_slots, bucket), np.int32)
+                starts = np.zeros(self.cfg.max_slots, np.int32)
+                valid = np.zeros(self.cfg.max_slots, np.int32)
+                for i, (chunk, n, _) in chunks.items():
+                    tokens[i, :n] = chunk
+                    starts[i] = self.prefill_pos[i]
+                    valid[i] = n
+            with span("qlm.engine.dispatch"):
+                if self.paged:
+                    # table refreshed AFTER the extends above so it names
+                    # this chunk's freshly allocated pages
+                    toks_out, self.cache = self._chunk_fn(
+                        self.params, self.cache, self._put(tokens),
+                        self._put(starts), self._put(valid),
+                        self._device_block_table())
+                else:
+                    toks_out, self.cache = self._chunk_fn(
+                        self.params, self.cache, self._put(tokens),
+                        self._put(starts), self._put(valid))
+            with span("qlm.engine.device_wait"):
+                # sync INSIDE the timed region: np.asarray(toks_out) alone
+                # only waits for the token array, leaving the cache update
+                # in flight — prefill_time would otherwise time async
+                # dispatch, not compute (and RWT calibration via profile()
+                # would under-report)
+                jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: one per chunk round, feeds prefill_time / RWT calibration
+                toks_out = np.asarray(toks_out)  # qlint: disable=host-sync-in-hot-path -- the round's single device->host result copy, inside the timed region
+            with span("qlm.engine.post"):
+                self.stats.prefill_chunks += 1
+                now = self.clock()
+                for i, (_, n, final) in chunks.items():
+                    req = self.slots[i]
+                    self.prefill_pos[i] += n
+                    self.lengths[i] = self.prefill_pos[i]
+                    if self.prefix_sharing:
+                        # publish the prompt blocks this chunk completed:
+                        # later admissions with the same leading tokens
+                        # attach to these pages instead of re-prefilling
+                        self.block_mgr.register_prefix(
+                            req.req_id, req.prompt_tokens,
+                            int(self.prefill_pos[i]))
+                    if final:
+                        tok = int(toks_out[i])
+                        if req.first_token_time is None:
+                            req.first_token_time = now
+                        req.output_tokens.append(tok)
+                        req.generated += 1
+                        self.stats.prefills += 1
+                        self._finish_if_done(i, tok, now, done)
+                self.stats.prefill_time += self._wall() - t0
 
     def _decode_round(self, done: List[Request]) -> None:
         active = self.decode_slots()
         if not active:
             return
-        t0 = self._wall()
-        # pending COW copies (previous round's append_token, fork_slot)
-        # must land before this dispatch writes the destination pages
-        self._apply_cow()
-        tokens = np.zeros(self.cfg.max_slots, np.int32)
-        for i in active:
-            tokens[i] = self.slots[i].output_tokens[-1] if self.slots[i].output_tokens \
-                else self.slots[i].prompt_tokens[-1]
-        if self.paged:
-            next_tokens, self.cache = self._decode_fn(
-                self.params, self.cache, self._put(tokens),
-                self._put(self.lengths),
-                self._device_block_table())
-        else:
-            next_tokens, self.cache = self._decode_fn(
-                self.params, self.cache, self._put(tokens),
-                self._put(self.lengths))
-        # sync the cache too (see _prefill_chunk_round): decode_time feeds
-        # the RWT estimator's decode_per_token via profile()
-        jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: one per decode round, feeds decode_time / RWT
-        next_tokens = np.asarray(next_tokens)  # qlint: disable=host-sync-in-hot-path -- the round's single device->host result copy, inside the timed region
-        self.stats.decode_iterations += 1
-        self.stats.decode_time += self._wall() - t0
-
-        now = self.clock()
-        for i in active:
-            req = self.slots[i]
-            # record the token FIRST: the decode step that produced it has
-            # already written its KV (at slot lengths), so neither a finish
-            # nor an OOM preemption below may drop it.
-            self.lengths[i] += 1
-            tok = int(next_tokens[i])
-            req.output_tokens.append(tok)
-            req.generated += 1
-            self.stats.tokens_generated += 1
-            if req.first_token_time is None:
-                req.first_token_time = now
-            if self._finish_if_done(i, tok, now, done):
-                continue
-            # reserve the NEXT decode step's KV slot; preempt on OOM
-            # (vLLM-style) — the just-produced token rides along in the
-            # eviction snapshot instead of being recomputed on resume.
-            if not self.block_mgr.append_token(req.req_id):
-                self.stats.preemptions += 1
-                self.evict_slot(i)
-                req._in_flight = False
+        with span("qlm.engine.decode", slots=len(active)):
+            with span("qlm.engine.prep"):
+                t0 = self._wall()
+                # pending COW copies (previous round's append_token,
+                # fork_slot) must land before this dispatch writes the
+                # destination pages
+                self._apply_cow()
+                tokens = np.zeros(self.cfg.max_slots, np.int32)
+                for i in active:
+                    tokens[i] = self.slots[i].output_tokens[-1] \
+                        if self.slots[i].output_tokens \
+                        else self.slots[i].prompt_tokens[-1]
+            with span("qlm.engine.dispatch"):
+                if self.paged:
+                    next_tokens, self.cache = self._decode_fn(
+                        self.params, self.cache, self._put(tokens),
+                        self._put(self.lengths),
+                        self._device_block_table())
+                else:
+                    next_tokens, self.cache = self._decode_fn(
+                        self.params, self.cache, self._put(tokens),
+                        self._put(self.lengths))
+            with span("qlm.engine.device_wait"):
+                # sync the cache too (see _prefill_chunk_round):
+                # decode_time feeds the RWT estimator's decode_per_token
+                # via profile()
+                jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: one per decode round, feeds decode_time / RWT
+                next_tokens = np.asarray(next_tokens)  # qlint: disable=host-sync-in-hot-path -- the round's single device->host result copy, inside the timed region
+            with span("qlm.engine.post"):
+                self.stats.decode_iterations += 1
+                self.stats.decode_time += self._wall() - t0
+                now = self.clock()
+                for i in active:
+                    req = self.slots[i]
+                    # record the token FIRST: the decode step that produced
+                    # it has already written its KV (at slot lengths), so
+                    # neither a finish nor an OOM preemption below may
+                    # drop it.
+                    self.lengths[i] += 1
+                    tok = int(next_tokens[i])
+                    req.output_tokens.append(tok)
+                    req.generated += 1
+                    self.stats.tokens_generated += 1
+                    if req.first_token_time is None:
+                        req.first_token_time = now
+                    if self._finish_if_done(i, tok, now, done):
+                        continue
+                    # reserve the NEXT decode step's KV slot; preempt on
+                    # OOM (vLLM-style) — the just-produced token rides
+                    # along in the eviction snapshot instead of being
+                    # recomputed on resume.
+                    if not self.block_mgr.append_token(req.req_id):
+                        self.stats.preemptions += 1
+                        self.evict_slot(i)
+                        req._in_flight = False
 
     def _plan_burst(self, active: List[int], k: int) -> int:
         """Largest burst width n <= k whose KV writes are FULLY coverable by
@@ -1381,57 +1405,65 @@ class ContinuousBatchingEngine:
         active = self.decode_slots()
         if not active:
             return
-        n = self._plan_burst(active, min(k, max(self.cfg.decode_burst, 1)))
-        if n == 0:
-            # pool at the preemption edge: the seed single-step logic owns
-            # OOM preemption ordering
-            self._decode_round(done)
-            return
-        t0 = self._wall()
-        # COW copies from _plan_burst's extends (and any earlier fork /
-        # append) must land before the fused loop writes those pages
-        self._apply_cow()
-        tokens = np.zeros(self.cfg.max_slots, np.int32)
-        remaining = np.zeros(self.cfg.max_slots, np.int32)
-        active_mask = np.zeros(self.cfg.max_slots, bool)
-        for i in active:
-            r = self.slots[i]
-            tokens[i] = r.output_tokens[-1] if r.output_tokens \
-                else r.prompt_tokens[-1]
-            remaining[i] = r.max_new_tokens - r.generated
-            active_mask[i] = True
-        bt = self._device_block_table() if self.paged else None
-        out, self.cache = self._burst_fn(
-            self.params, self.cache, self._put(tokens),
-            self._put(self.lengths), self._put(remaining),
-            self._put(active_mask), self._put(np.int32(n)), bt)
-        self.stats.decode_bursts += 1
-        jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: THE single per-burst host sync the device-resident loop budgets for
-        out = np.asarray(out)  # qlint: disable=host-sync-in-hot-path -- the burst's single device->host result copy, inside the timed region
-        executed = int((out >= 0).any(axis=1).sum())
-        self.stats.decode_iterations += executed
-        self.stats.decode_time += self._wall() - t0
-
-        now = self.clock()
-        for i in active:
-            req = self.slots[i]
-            for j in range(executed):
-                tok = int(out[j, i])
-                if tok < 0:
-                    break  # slot went inactive on device at iteration j
-                self.lengths[i] += 1
-                req.output_tokens.append(tok)
-                req.generated += 1
-                self.stats.tokens_generated += 1
-                if req.first_token_time is None:
-                    req.first_token_time = now
-                if self._finish_if_done(i, tok, now, done):
-                    break
-            else:
-                # survived the whole burst: the up-front reservation left
-                # exactly the single-step invariant (lengths + 1 tokens)
-                assert self.block_mgr.seq_tokens(req.req_id) \
-                    == int(self.lengths[i]) + 1
+        with span("qlm.engine.burst", slots=len(active)):
+            with span("qlm.engine.prep"):
+                n = self._plan_burst(
+                    active, min(k, max(self.cfg.decode_burst, 1)))
+            if n == 0:
+                # pool at the preemption edge: the seed single-step logic
+                # owns OOM preemption ordering
+                self._decode_round(done)
+                return
+            with span("qlm.engine.prep"):
+                t0 = self._wall()
+                # COW copies from _plan_burst's extends (and any earlier
+                # fork / append) must land before the fused loop writes
+                # those pages
+                self._apply_cow()
+                tokens = np.zeros(self.cfg.max_slots, np.int32)
+                remaining = np.zeros(self.cfg.max_slots, np.int32)
+                active_mask = np.zeros(self.cfg.max_slots, bool)
+                for i in active:
+                    r = self.slots[i]
+                    tokens[i] = r.output_tokens[-1] if r.output_tokens \
+                        else r.prompt_tokens[-1]
+                    remaining[i] = r.max_new_tokens - r.generated
+                    active_mask[i] = True
+                bt = self._device_block_table() if self.paged else None
+            with span("qlm.engine.dispatch", n=n):
+                out, self.cache = self._burst_fn(
+                    self.params, self.cache, self._put(tokens),
+                    self._put(self.lengths), self._put(remaining),
+                    self._put(active_mask), self._put(np.int32(n)), bt)
+                self.stats.decode_bursts += 1
+            with span("qlm.engine.device_wait"):
+                jax.block_until_ready(self.cache)  # qlint: disable=host-sync-in-hot-path -- documented timed-region sync: THE single per-burst host sync the device-resident loop budgets for
+                out = np.asarray(out)  # qlint: disable=host-sync-in-hot-path -- the burst's single device->host result copy, inside the timed region
+            with span("qlm.engine.post"):
+                executed = int((out >= 0).any(axis=1).sum())
+                self.stats.decode_iterations += executed
+                self.stats.decode_time += self._wall() - t0
+                now = self.clock()
+                for i in active:
+                    req = self.slots[i]
+                    for j in range(executed):
+                        tok = int(out[j, i])
+                        if tok < 0:
+                            break  # slot went inactive on device at step j
+                        self.lengths[i] += 1
+                        req.output_tokens.append(tok)
+                        req.generated += 1
+                        self.stats.tokens_generated += 1
+                        if req.first_token_time is None:
+                            req.first_token_time = now
+                        if self._finish_if_done(i, tok, now, done):
+                            break
+                    else:
+                        # survived the whole burst: the up-front
+                        # reservation left exactly the single-step
+                        # invariant (lengths + 1 tokens)
+                        assert self.block_mgr.seq_tokens(req.req_id) \
+                            == int(self.lengths[i]) + 1
 
     def _admit_from_pull(self) -> None:
         """Request pulling: admit while capacity allows; a refused request
@@ -1442,22 +1474,24 @@ class ContinuousBatchingEngine:
         # refusal — taking the pushback back into the queue happens inside
         # the puller (lso._pull), so gating the loop on `_pushback is None`
         # would freeze admission forever after the first refusal
-        while self._free_slot() is not None:
-            req = self.pull_source()
-            if req is None:
-                break
-            if not self.admit(req):
-                # pool-pressure valve: evicted requests' snapshot pins can
-                # accumulate until no admission fits (sustained shedding
-                # under overload).  Materialize the pinned snapshots —
-                # their prefix pages move to host memory and the pins are
-                # released — then retry once before pushing back.
-                if self._pinned_snapshots:
-                    self._materialize_pinned_snapshots()
-                    if self.admit(req):
-                        continue
-                self._pushback = req
-                break
+        with span("qlm.engine.admit"):
+            while self._free_slot() is not None:
+                req = self.pull_source()
+                if req is None:
+                    break
+                if not self.admit(req):
+                    # pool-pressure valve: evicted requests' snapshot pins
+                    # can accumulate until no admission fits (sustained
+                    # shedding under overload).  Materialize the pinned
+                    # snapshots — their prefix pages move to host memory
+                    # and the pins are released — then retry once before
+                    # pushing back.
+                    if self._pinned_snapshots:
+                        self._materialize_pinned_snapshots()
+                        if self.admit(req):
+                            continue
+                    self._pushback = req
+                    break
 
     def step(self) -> List[Request]:
         """Admit from the pull source, run one prefill chunk round, then one
@@ -1522,7 +1556,8 @@ class ContinuousBatchingEngine:
             self._inv_sampler = InvariantSampler()
         if self._inv_sampler.due():
             from repro.analysis.invariants import check_engine
-            check_engine(self, where=f"engine:{self.model_name}/round")
+            with span("qlm.engine.invariants"):
+                check_engine(self, where=f"engine:{self.model_name}/round")
 
     # ------------------------------------------------------------------
     # profiling (feeds the RWT estimator + simulator)

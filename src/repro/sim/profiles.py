@@ -82,8 +82,8 @@ def calibrate_from_engine(engine, token_capacity: int,
     host overhead is already amortized INTO the measurement at that burst
     width; the profile carries the width so the simulator charges the same
     amortization.  Pass ``dispatch_overhead`` (absolute seconds per
-    dispatch, e.g. derived from engine_bench's host_overhead_fraction x
-    wall_us_per_iter) to model re-running the same instance at a DIFFERENT
+    dispatch, e.g. the chip's ``agent_host_ms_per_round`` from the
+    benchmark's trace) to model re-running the same instance at a DIFFERENT
     burst width without re-profiling."""
     import numpy as np
     # the longest calibration prompt that fits alongside the decode budget:

@@ -13,6 +13,7 @@ imported: only one process at a time may load the TPU library, and every
 test worker imports every test file.  This is the only file that does it.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +131,12 @@ def test_full_width_step_fits_one_chip(granite, one_chip, no_persistent_cache,
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *data).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's instruction carries its name (``<kernel>`` or
+    # ``<kernel>.<n>``): the profiler's "XLA Ops" name it so on the chip
+    kernel = {"decode": "paged_decode_attention",
+              "prefill_chunk": "paged_prefill_attention"}[step]
+    assert re.search(rf"^\s*%{kernel}(\.\d+)? = .*custom-call\(",
+                     compiled.as_text(), re.M), kernel
     mem = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
