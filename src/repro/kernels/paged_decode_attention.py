@@ -12,19 +12,34 @@ physically non-contiguous, so the eviction / swapping / admission LSOs can
 reclaim and reassign HBM at block granularity instead of per-slot
 ``max_seq_len`` stripes.
 
-Grid (batch, kv_head, kv_tile).  The block table and per-sequence
-``lengths`` ride in scalar-prefetch SMEM (``PrefetchScalarGridSpec``), so
-the k/v ``index_map`` can translate logical block ids into physical page
-ids BEFORE the DMA is issued — the gather happens in the pipeline's
-address computation, not as a materialized copy.  Each kv tile fetches
-``pages_per_tile`` pages (replicated k/v inputs whose index_maps read
-consecutive block-table entries), so small ``block_size`` pools still fill
-MXU tiles; ``pages_per_tile=None`` auto-derives the width from
-``block_size`` (``auto_pages_per_tile`` targets 128-row tiles).  As in the
-dense kernel, the whole GQA head-group's queries ride along in one tile;
-tiles fully past ``lengths[b]`` skip compute via ``pl.when`` and skip
-their DMAs too (dead logical blocks clamp to the last live one in the
-index_map, so the unchanged block index pipeline-elides the copy).
+A kv tile is ``pages_per_tile`` consecutive logical pages of one sequence:
+``pages_per_tile * block_size`` tokens of all KVH heads (``None``
+auto-derives 128-token tiles, ``auto_pages_per_tile``).  A page is fetched
+whole, every kv head at once: ``(KVH, block_size, D)`` is one contiguous
+block of the pool.  The query is ``(KVH, group, D)``: each tile is scored
+with one dot per kv head, batched over the kv heads, masked to the live
+length, and folded into one online softmax (f32, ``1/sqrt(D)``) per query
+head.  The block table and ``lengths`` ride in scalar-prefetch SMEM
+(``PrefetchScalarGridSpec``); sentinel table entries are clamped to a
+real page and masked by ``lengths``.
+
+The route follows the static page shape (``_dma_sliceable``):
+
+* lane-aligned pages (head_dim a multiple of 128, float pools): grid
+  ``(batch,)``.  Each step walks its slot's ``ceil(lengths[b] / tile)``
+  live tiles in a ``fori_loop``, fetching each live page with one DMA per
+  pool (``memory_space=pl.ANY``, ``make_async_copy``) into a double
+  buffer: tile ``t + 1``'s pages are in flight while tile ``t`` is
+  computed.  Pages past the live length are never fetched; the buffers
+  are zeroed once per call so their stale rows stay finite under the mask.
+* any other page (head_dim 64, int8 scale pages): Mosaic refuses a DMA
+  slice of it (a 128-lane view of the pool would slice, but in the served
+  step XLA lays the pool out anew for it: two more pool-sized copies a
+  layer), so the grid is ``(batch, kv_tile)`` over the whole table,
+  with one whole-page ``BlockSpec`` per page of the tile.  Tiles past
+  ``lengths[b]`` skip compute via ``pl.when`` and their DMAs too (their
+  index_map clamps to the last live page, and the unchanged block index
+  elides the copy), but each still costs a grid step.
 
 ``lengths`` counts every valid cache slot INCLUDING the newest token (the
 same inclusive convention as ``decode_attention`` /
@@ -46,14 +61,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 NEG_INF = -1e30
+_LANES = 128
 
-# Target kv-tile rows per grid step: one MXU-aligned 128-row tile.  A pool
-# with block_size 8 fetches 16 pages per step, block_size 128+ fetches 1.
+# Target tokens per kv tile: one MXU-aligned 128-row tile per kv head.  A
+# pool with block_size 8 takes 16 pages per tile, block_size 128+ takes 1.
 _TARGET_TILE_ROWS = 128
 
 
 def auto_pages_per_tile(block_size: int, nb: int) -> int:
-    """Pages fetched per grid step so a kv tile approaches 128 rows
+    """Pages per kv tile so a tile approaches 128 tokens
     (``_TARGET_TILE_ROWS``) without exceeding the table width ``nb``."""
     p = max(1, _TARGET_TILE_ROWS // max(block_size, 1))
     return max(1, min(p, nb))
@@ -93,26 +109,32 @@ def _live_block_index(logical: jax.Array, tokens: jax.Array,
     return jnp.minimum(jnp.minimum(logical, last_live), width - 1)
 
 
-def _online_softmax_update(s, v, m_scr, l_scr, acc_scr):
+def _online_softmax_update(s, v, m_scr, l_scr, acc_scr, p_scale=None):
     """One online-softmax accumulation step shared by the paged decode and
     prefill-chunk kernels: fold score tile ``s`` (rows_q, rows_kv) and
     value tile ``v`` (rows_kv, D) into the running max / denominator /
-    accumulator scratch."""
+    accumulator scratch; leading dims of all five are batch dims (one per
+    kv head in decode).  ``p_scale`` (..., 1, rows_kv), when given, scales
+    each kv row's weight in the accumulator only (int8 v's per-token
+    scale)."""
     m_prev = m_scr[...]
     l_prev = l_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new[..., None])
     l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    pv = p if p_scale is None else p * p_scale
+    batch = tuple(range(s.ndim - 2))
+    acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
+        pv, v, (((s.ndim - 1,), (s.ndim - 2,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
 
 def _assemble_kv_tile(k_refs, v_refs, ks_refs, vs_refs, P: int):
     """Concatenate the P replicated page refs into one (P*bs, D) f32 k/v
     tile, fusing the per-row int8 dequant in VMEM when scale refs are
-    given (shared by the decode and prefill-chunk kernels)."""
+    given (the prefill-chunk kernel's per-head page refs)."""
     if ks_refs is not None:
         k_parts = [k_refs[p][0, 0].astype(jnp.float32)
                    * ks_refs[p][0, 0].astype(jnp.float32)[:, None]
@@ -128,57 +150,137 @@ def _assemble_kv_tile(k_refs, v_refs, ks_refs, vs_refs, P: int):
     return k, v
 
 
-def _make_decode_kernel(*, P: int, scale: float, block_size: int,
-                        quant: bool):
-    """Kernel body closure.  Tensor-ref layout after the 2 scalar-prefetch
-    refs (block table, lengths):
-      q, k_page*P, v_page*P, [k_scale*P, v_scale*P,] o, m_scr, l_scr, acc_scr
+def _dma_sliceable(pool: jax.Array) -> bool:
+    """Whether a DMA may slice one page (KVH, bs, D) out of ``pool``:
+    Mosaic refuses a slice whose last two dims are not whole tiles of the
+    dtype, so a head_dim of 64 or a (KVH, bs) scale page is not."""
+    sublanes = 32 // pool.dtype.itemsize
+    return pool.shape[-1] % _LANES == 0 and pool.shape[-2] % sublanes == 0
+
+
+def _make_decode_kernel(*, P: int, block_size: int, width: int, scale: float,
+                        quant: bool, manual_dma: bool):
+    """Kernel body closure.  Refs after the 2 scalar-prefetch refs (block
+    table, lengths) and the q block:
+
+      manual DMA: k_pool, v_pool, o, k_buf, v_buf (two tiles each), sems,
+                  m_scr, l_scr, acc_scr
+      pipelined:  P page blocks for each of k, v [, k_scale, v_scale], o,
+                  m_scr, l_scr, acc_scr
     """
+    tile_tokens = P * block_size
 
-    def kernel(bt_ref, len_ref, q_ref, *refs):
-        del bt_ref  # consumed by the index_maps (page translation)
-        k_refs = refs[:P]
-        v_refs = refs[P:2 * P]
-        if quant:
-            ks_refs = refs[2 * P:3 * P]
-            vs_refs = refs[3 * P:4 * P]
-            o_ref, m_scr, l_scr, acc_scr = refs[4 * P:]
-        else:
-            ks_refs = vs_refs = None
-            o_ref, m_scr, l_scr, acc_scr = refs[2 * P:]
+    def init(m_scr, l_scr, acc_scr):
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def update(q_ref, tiles, t, length, m_scr, l_scr, acc_scr):
+        """Fold kv tile ``t`` ((KVH, P*bs, D) k and v [and (KVH, P*bs)
+        scales]) into the running softmax of every query head: one dot
+        per kv head, batched over the kv heads."""
+        k, v = tiles[0], tiles[1]
+        q = q_ref[0].astype(jnp.float32)                  # (KVH, group, D)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        p_scale = None
+        if quant:  # per-token scales: k's scale the score, v's the weight
+            s = s * tiles[2][:, None, :]
+            p_scale = tiles[3][:, None, :]
+        pos = t * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(pos < length, s, NEG_INF)
+        _online_softmax_update(s, v, m_scr, l_scr, acc_scr, p_scale=p_scale)
+
+    def finalize(o_ref, l_scr, acc_scr):
+        denom = jnp.maximum(l_scr[...], 1e-20)
+        o_ref[0] = (acc_scr[...] / denom[..., None]).astype(o_ref.dtype)
+
+    def manual_kernel(bt_ref, len_ref, q_ref, k_pool, v_pool, o_ref, k_buf,
+                      v_buf, sems, m_scr, l_scr, acc_scr):
         b = pl.program_id(0)
-        i = pl.program_id(2)
-        nt = pl.num_programs(2)
         length = len_ref[b]  # valid tokens in this sequence (incl. newest)
+        live_pages = (length + block_size - 1) // block_size
+        n_tiles = (length + tile_tokens - 1) // tile_tokens
 
-        @pl.when(i == 0)
+        def page_copies(t, slot):
+            """(live, copy) for each page DMA of tile ``t`` into buffer
+            ``slot``: one whole page, every kv head, per pool."""
+            out = []
+            for p in range(P):
+                logical = t * P + p
+                page = bt_ref[b, jnp.minimum(logical, width - 1)]
+                rows = pl.ds(p * block_size, block_size)
+                for i, (pool, buf) in enumerate(((k_pool, k_buf),
+                                                 (v_pool, v_buf))):
+                    out.append((logical < live_pages, pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, :, rows],
+                        sems.at[slot, i, p])))
+            return out
+
+        def start(t, slot):
+            for live, copy in page_copies(t, slot):
+                pl.when(live)(copy.start)
+
+        def wait(t, slot):
+            for live, copy in page_copies(t, slot):
+                pl.when(live)(copy.wait)
+
+        # pages past the live length are never fetched, so a buffer row
+        # holds an earlier tile's page or, before the first fetch, whatever
+        # VMEM held: zero the buffers once per call so a masked row is
+        # always finite (0 * NaN would reach the accumulator)
+        @pl.when(b == 0)
+        def _zero():
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+
+        init(m_scr, l_scr, acc_scr)
+
+        @pl.when(n_tiles > 0)
+        def _first():
+            start(0, 0)
+
+        def body(t, carry):
+            slot = t % 2
+
+            @pl.when(t + 1 < n_tiles)
+            def _prefetch():
+                start(t + 1, 1 - slot)
+
+            wait(t, slot)
+            tiles = [buf[slot].astype(jnp.float32) for buf in (k_buf, v_buf)]
+            update(q_ref, tiles, t, length, m_scr, l_scr, acc_scr)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, body, 0)
+        finalize(o_ref, l_scr, acc_scr)
+
+    def pipelined_kernel(bt_ref, len_ref, q_ref, *refs):
+        del bt_ref  # consumed by the index_maps (page translation)
+        n_src = 4 if quant else 2
+        page_refs = refs[:n_src * P]
+        o_ref, m_scr, l_scr, acc_scr = refs[n_src * P:]
+        b = pl.program_id(0)
+        t = pl.program_id(1)
+        length = len_ref[b]
+
+        @pl.when(t == 0)
         def _init():
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-            acc_scr[...] = jnp.zeros_like(acc_scr)
+            init(m_scr, l_scr, acc_scr)
 
-        tile_rows = P * block_size
-        k_start = i * tile_rows
-
-        @pl.when(k_start < length)
+        @pl.when(t * tile_tokens < length)
         def _compute():
-            q = q_ref[0, 0].astype(jnp.float32)      # (group, d)
-            # per-row scales live in their own scale pages; the dequant
-            # happens in VMEM (the HBM read stays int8 + scales)
-            k, v = _assemble_kv_tile(k_refs, v_refs, ks_refs, vs_refs, P)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos < length, s, NEG_INF)
-            _online_softmax_update(s, v, m_scr, l_scr, acc_scr)
+            # each pool's P whole pages (KVH, bs, ...) -> (KVH, P*bs, ...)
+            pools = [page_refs[i * P:(i + 1) * P] for i in range(n_src)]
+            tiles = [jnp.concatenate([r[0].astype(jnp.float32) for r in rs],
+                                     axis=1) for rs in pools]
+            update(q_ref, tiles, t, length, m_scr, l_scr, acc_scr)
 
-        @pl.when(i == nt - 1)
+        @pl.when(t == pl.num_programs(1) - 1)
         def _finalize():
-            denom = jnp.maximum(l_scr[...], 1e-20)
-            o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+            finalize(o_ref, l_scr, acc_scr)
 
-    return kernel
+    return manual_kernel if manual_dma else pipelined_kernel
 
 
 def _decode_call(q, k_pages, v_pages, block_table, lengths, scale_pages, *,
@@ -195,61 +297,62 @@ def _decode_call(q, k_pages, v_pages, block_table, lengths, scale_pages, *,
 
     P = pages_per_tile or auto_pages_per_tile(bs, nb)
     P = max(1, min(P, nb))
-    nt = -(-nb // P)
-    W = nt * P
     qg = q.reshape(B, KVH, group, D)
-    bt = _pad_block_table(block_table, N, W)
+    pools = [k_pages, v_pages] + list(scale_pages or ())
+    manual_dma = all(_dma_sliceable(a) for a in pools)
+    kernel = _make_decode_kernel(P=P, block_size=bs, width=nb, scale=scale,
+                                 quant=quant, manual_dma=manual_dma)
+    scratch = [pltpu.VMEM((KVH, group), jnp.float32),
+               pltpu.VMEM((KVH, group), jnp.float32),
+               pltpu.VMEM((KVH, group, D), jnp.float32)]
+    if manual_dma:
+        grid = (B,)
+        per_slot = lambda b, bt_ref, len_ref: (b, 0, 0, 0)  # noqa: E731
+        page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        inputs = pools
+        scratch = [pltpu.VMEM((2, KVH, P * bs, D), k_pages.dtype),
+                   pltpu.VMEM((2, KVH, P * bs, D), v_pages.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2, P))] + scratch
+        table = _clamp_table(block_table, N)
+        # the buffers are zeroed at slot 0 only: slots run in order
+        semantics = ("arbitrary",)
+    else:
+        nt = -(-nb // P)
+        W = nt * P
+        grid = (B, nt)
+        per_slot = lambda b, t, bt_ref, len_ref: (b, 0, 0, 0)  # noqa: E731
 
-    def _q_idx(b, h, i, bt_ref, len_ref):
-        return (b, h, 0, 0)
+        def _page_idx(b, t, bt_ref, len_ref, *, p, rank):
+            # logical block t*P+p of sequence b -> physical page; blocks
+            # past the live prefix clamp to the last live block so dead
+            # tiles keep an unchanged index and their DMAs are elided
+            idx = _live_block_index(t * P + p, len_ref[b], bs, W)
+            return (bt_ref[b, idx],) + (0,) * (rank - 1)
 
-    def _page_idx(b, h, i, bt_ref, len_ref, *, p):
-        # logical block i*P+p of sequence b -> physical page; blocks past
-        # the live prefix clamp to the last live block so dead tiles keep
-        # an unchanged index and their DMAs are pipeline-skipped
-        idx = _live_block_index(i * P + p, len_ref[b], bs, W)
-        return (bt_ref[b, idx], h, 0, 0)
+        page_specs, inputs = [], []
+        for a in pools:
+            page_specs += [pl.BlockSpec((1,) + a.shape[1:], functools.partial(
+                _page_idx, p=p, rank=a.ndim)) for p in range(P)]
+            inputs += [a] * P
+        table = _pad_block_table(block_table, N, W)
+        semantics = ("parallel", "arbitrary")
 
-    def _scale_idx(b, h, i, bt_ref, len_ref, *, p):
-        idx = _live_block_index(i * P + p, len_ref[b], bs, W)
-        return (bt_ref[b, idx], h, 0)
-
-    page_spec = lambda p: pl.BlockSpec(  # noqa: E731
-        (1, 1, bs, D), functools.partial(_page_idx, p=p))
-    in_specs = [pl.BlockSpec((1, 1, group, D), _q_idx)]
-    in_specs += [page_spec(p) for p in range(P)]
-    in_specs += [page_spec(p) for p in range(P)]
-    inputs = [qg] + [k_pages] * P + [v_pages] * P
-    if quant:
-        k_scale_pages, v_scale_pages = scale_pages
-        sspec = lambda p: pl.BlockSpec(  # noqa: E731
-            (1, 1, bs), functools.partial(_scale_idx, p=p))
-        in_specs += [sspec(p) for p in range(P)]
-        in_specs += [sspec(p) for p in range(P)]
-        inputs += [k_scale_pages] * P + [v_scale_pages] * P
-
-    kernel = _make_decode_kernel(P=P, scale=scale, block_size=bs, quant=quant)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + lengths, prefetched to SMEM
-        grid=(B, KVH, nt),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, D), _q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
-        ],
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, KVH, group, D), per_slot)] + page_specs,
+        out_specs=pl.BlockSpec((1, KVH, group, D), per_slot),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name=("paged_decode_attention_quant" if quant
               else "paged_decode_attention"),
-    )(bt, lengths.astype(jnp.int32), *inputs)
+    )(table, lengths.astype(jnp.int32), qg, *inputs)
     return out.reshape(B, H, D)
 
 

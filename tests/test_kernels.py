@@ -193,6 +193,105 @@ def test_decode_attention_quant_length_convention(length):
                                rtol=2e-4, atol=2e-4)
 
 
+# ragged live-page walk: one batch holds an empty slot (1 token), exactly
+# one page, one page and a token, a length ending mid-tile and the whole
+# table; entries past each slot's live pages are sentinels (= pool size)
+_WALK_BS, _WALK_NB = 8, 24
+
+
+def _live_walk_case(rng, *, H, KVH, D, quant=False):
+    bs, nb = _WALK_BS, _WALK_NB
+    lengths = np.array([1, bs, bs + 1, 77, 150, nb * bs], np.int32)
+    B, N = lengths.size, 3 * lengths.size * nb
+    q = rng.standard_normal((B, H, D)).astype(np.float32)
+    if quant:
+        kp = rng.integers(-127, 128, size=(N, KVH, bs, D)).astype(np.int8)
+        vp = rng.integers(-127, 128, size=(N, KVH, bs, D)).astype(np.int8)
+    else:
+        kp = rng.standard_normal((N, KVH, bs, D)).astype(np.float32)
+        vp = rng.standard_normal((N, KVH, bs, D)).astype(np.float32)
+    bt = rng.permutation(N)[:B * nb].reshape(B, nb).astype(np.int32)
+    for b, n in enumerate(lengths):
+        bt[b, -(-n // bs):] = N
+    return q, kp, vp, bt, lengths
+
+
+@pytest.mark.parametrize("pages_per_tile", [1, None, 16])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("group", [4, 8])
+def test_paged_decode_live_page_walk(group, D, pages_per_tile):
+    """The live-page walk == the gather oracle for ragged lengths,
+    sentinel tails, every tile width and both head_dim routes (64: whole-
+    page BlockSpecs; 128: the manual-DMA loop)."""
+    rng = np.random.default_rng(100 + group + D)
+    KVH = 2
+    q, kp, vp, bt, lengths = _live_walk_case(rng, H=group * KVH, KVH=KVH,
+                                             D=D)
+    out = ops.paged_decode_attention(q, kp, vp, bt, lengths,
+                                     pages_per_tile=pages_per_tile)
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("pages_per_tile", [1, None])
+@pytest.mark.parametrize("D", [64, 128])
+def test_paged_decode_quant_live_page_walk(D, pages_per_tile):
+    """int8 twin over the same ragged batch == dequantized oracle."""
+    rng = np.random.default_rng(200 + D)
+    KVH = 2
+    q, kq, vq, bt, lengths = _live_walk_case(rng, H=4 * KVH, KVH=KVH, D=D,
+                                             quant=True)
+    N = kq.shape[0]
+    ks = (rng.random((N, KVH, _WALK_BS)) * 0.1).astype(np.float32)
+    vs = (rng.random((N, KVH, _WALK_BS)) * 0.1).astype(np.float32)
+    out = ops.paged_decode_attention_quant(q, kq, vq, ks, vs, bt, lengths,
+                                           pages_per_tile=pages_per_tile)
+    from repro.kernels.paged_decode_attention import gather_kv_pages_fused
+    kd, vd = gather_kv_pages_fused(jnp.asarray(kq), jnp.asarray(vq),
+                                   jnp.asarray(bt))
+    ksd, vsd = gather_kv_pages_fused(jnp.asarray(ks), jnp.asarray(vs),
+                                     jnp.asarray(bt))
+    k = np.asarray(kd, np.float32) * np.asarray(ksd)[..., None]
+    v = np.asarray(vd, np.float32) * np.asarray(vsd)[..., None]
+    want = ref.decode_attention_ref(jnp.asarray(q), k, v, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def _decode_grid(D, nb, B=4, H=8, KVH=2, bs=16, N=512):
+    """The grid of the decode ``pallas_call`` traced for a (B, nb) table."""
+    args = (jax.ShapeDtypeStruct((B, H, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((N, KVH, bs, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((N, KVH, bs, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((B, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+    jaxpr = jax.make_jaxpr(ops.paged_decode_attention)(*args)
+
+    def calls(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (eqn,) = calls(jaxpr.jaxpr)
+    return tuple(eqn.params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("D", [64, 128])
+def test_paged_decode_grid_has_no_kv_head_axis(D):
+    """head_dim 128 walks live pages inside one grid step per slot, so its
+    grid does not grow with the table; head_dim 64 steps (slot, tile) over
+    the table with whole pages.  Neither has a kv-head axis."""
+    B, P = 4, 8  # auto tile width: 8 pages of 16 tokens
+    grids = {nb: _decode_grid(D, nb, B=B) for nb in (32, 256)}
+    if D == 128:
+        assert grids == {32: (B,), 256: (B,)}
+    else:
+        assert grids == {32: (B, 32 // P), 256: (B, 256 // P)}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32])
 @pytest.mark.parametrize("B,L,H,P,G,N,chunk", [
     (1, 64, 2, 16, 1, 8, 16),

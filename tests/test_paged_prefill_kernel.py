@@ -251,7 +251,7 @@ def test_engine_long_chunk_q_tiled_token_parity():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("bs", [8, 16])
-@pytest.mark.parametrize("pages_per_tile", [1, 2, 4, None])
+@pytest.mark.parametrize("pages_per_tile", [1, 2, 4, None, 16])
 @pytest.mark.pallas
 def test_paged_decode_multi_page_tiles(bs, pages_per_tile):
     """pages_per_tile is a pure perf reshaping: identical outputs for

@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with jax, and it compiles for a topology that
 is described, not attached.  These tests compile the paged Pallas kernels
-and the full-width granite-3-2b bf16 decode and prefill-chunk steps for one
-v5e chip and check what only that compiler can show: that the kernels
+(the decode kernel at granite-3-2b's and deepseek-67b's widths, one per
+route) and the full-width granite-3-2b bf16 decode and prefill-chunk steps
+for one v5e chip and check what only that compiler can show: that the kernels
 lower to Mosaic (``tpu_custom_call``) rather than interpreted HLO, and that
 the steps fit the chip's 16 GiB of HBM.  Nothing runs; a compile that
 passes is not a chip run.
@@ -69,17 +70,23 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kernel_inputs(cfg, sharding):
+def _kernel_inputs(cfg, sharding, max_seq=MAX_SEQ, pool_blocks=POOL_BLOCKS):
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    pages = _spec((POOL_BLOCKS, KVH, BLOCK, D), jnp.bfloat16, sharding)
-    table = _spec((SLOTS, MAX_SEQ // BLOCK), jnp.int32, sharding)
+    pages = _spec((pool_blocks, KVH, BLOCK, D), jnp.bfloat16, sharding)
+    table = _spec((SLOTS, max_seq // BLOCK), jnp.int32, sharding)
     per_seq = _spec((SLOTS,), jnp.int32, sharding)
     return H, KVH, D, pages, table, per_seq
 
 
-def test_paged_decode_kernel_lowers_to_mosaic(granite, one_chip,
-                                              no_persistent_cache):
-    H, KVH, D, pages, table, lengths = _kernel_inputs(granite, one_chip)
+@pytest.mark.parametrize("arch,max_seq,pool_blocks", [
+    ("granite-3-2b", MAX_SEQ, POOL_BLOCKS),  # head_dim 64, 128-page table
+    ("deepseek-67b", 4096, 4096),            # head_dim 128, 256-page table
+])
+def test_paged_decode_kernel_lowers_to_mosaic(arch, max_seq, pool_blocks,
+                                              one_chip, no_persistent_cache):
+    cfg = get_arch(arch)
+    H, KVH, D, pages, table, lengths = _kernel_inputs(
+        cfg, one_chip, max_seq=max_seq, pool_blocks=pool_blocks)
     q = _spec((SLOTS, H, D), jnp.bfloat16, one_chip)
     text = ops.paged_decode_attention.lower(
         q, pages, pages, table, lengths, interpret=False).compile().as_text()
