@@ -17,7 +17,7 @@ from bench.harness import check as check_lib
 from bench.harness import stats as stats_lib
 from bench.harness import traffic as traffic_lib
 from bench.harness import work as work_lib
-from bench.harness.spec import BENCH_DIR, Cell, metric_path
+from bench.harness.spec import BENCH_DIR, Cell, arch_module, metric_path
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -82,7 +82,8 @@ class Context:
     max_slots: int
     tick_seconds: List[float]
     round_work: List[Any]
-    dims: Dict[str, Any]
+    dims: Dict[str, Any]                 # the reference module's dims
+    arch: Any                            # the architecture module
     peak: Dict[str, float]
     memory: Dict[str, Any]
     trace: Optional[Any] = None          # harness.trace.Trace
@@ -103,7 +104,7 @@ def _stats_dict(engine) -> Dict[str, Any]:
 
 
 def _checksums(params) -> Dict[str, List[int]]:
-    """Bitwise checksums of the served weights, keyed as the reference's
+    """Bitwise checksums of every served weight, keyed as the reference's
     ``checksums``: per leaf, the sum of its bf16 bit patterns (per layer
     for the stacked decoder layers)."""
     import jax
@@ -118,7 +119,7 @@ def _checksums(params) -> Dict[str, List[int]]:
         if name.startswith("blocks/"):
             out[name] = [int(x) for x in jax.jit(
                 bits, static_argnums=1)(a, tuple(range(1, a.ndim)))]
-        elif name in ("embed", "lm_head"):
+        else:
             out[name] = [int(jax.jit(bits, static_argnums=1)(a, None))]
     return out
 
@@ -209,6 +210,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
                       tick_seconds=served.tick_seconds,
                       round_work=served.round_work,
                       dims=check_lib.reference_module(config).dims(config),
+                      arch=arch_module(config),
                       peak=peak, memory=memory, trace=tr, reduced=reduced)
         metrics = {}
         for m in cell.per_layer:

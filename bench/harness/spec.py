@@ -2,11 +2,13 @@
 
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric sits in a file of its own under ``bench/``; this module
-resolves the names ``BENCHMARK.json`` gives to those files.
+resolves the names ``BENCHMARK.json`` gives to those files, and a
+configuration's architecture module by the name its file gives.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import pathlib
 from typing import Any, Dict, List
@@ -70,23 +72,13 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)])
 
 
+def arch_module(config: Dict[str, Any]):
+    """The configuration's architecture module, ``bench/arch/<name>.py``,
+    by the name its file gives under ``reference``."""
+    return importlib.import_module(f"bench.arch.{config['reference']}")
+
+
 def model_config(config: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file (keys named
-    as in the model's published ``config.json``)."""
-    from repro.configs.base import ModelConfig
-    heads = config["num_attention_heads"]
-    head_dim = config.get("head_dim", config["hidden_size"] // heads)
-    if head_dim * heads != config["hidden_size"] and "head_dim" not in config:
-        raise SpecError("hidden_size is not a multiple of the head count")
-    return ModelConfig(
-        name=config["name"], arch_type="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"], num_heads=heads,
-        num_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        head_dim=config.get("head_dim"),
-        qkv_bias=config.get("attention_bias", False),
-        rope_theta=config["rope_theta"],
-        rms_norm_eps=config["rms_norm_eps"],
-        tie_embeddings=config["tie_word_embeddings"],
-        source=config["source"])
+    """The program's ``ModelConfig`` for a configuration file, as its
+    architecture module reads it."""
+    return arch_module(config).model_config(config)

@@ -2,18 +2,14 @@
 (prompt tokens prefilled and tokens decoded, each 2 x N matmul FLOPs per
 layer plus attention over its live context, and the output head for each
 token produced) over traced seconds x the chip's peak bf16 FLOP/s, in %.
-Counted from the live lengths of the rounds that ran inside the trace."""
-from bench.harness import work
+Counted by the configuration's architecture module from the live
+lengths of the rounds that ran inside the trace."""
 
 
 def flops(ctx, rounds):
-    d = ctx.dims
-    return sum(work.model_flops(
-        layers=d["layers"], d_model=d["d"], heads=d["heads"],
-        kv_heads=d["kv_heads"], head_dim=d["head_dim"], d_ff=d["ff"],
-        vocab=d["vocab"], prefill_spans=w.prefill_spans,
-        decode_contexts=w.decode_contexts, produced=w.produced)
-        for w in rounds)
+    return sum(ctx.arch.model_flops(ctx.dims, w.prefill_spans,
+                                    w.decode_contexts, w.produced)
+               for w in rounds)
 
 
 def read(ctx):

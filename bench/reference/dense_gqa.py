@@ -300,6 +300,8 @@ def checksums(config: Dict[str, Any], key) -> Dict[str, List[int]]:
         for path, a in _flat(layer_weights(config, key, layer)):
             out.setdefault(f"blocks/{path}", []).append(_bits(a))
     out["embed"] = [_bits(embed_weights(config, key))]
+    # the final norm's scale is drawn as ones, as ``_head`` applies it
+    out["final_norm"] = [_bits(jnp.ones((m["d"],), jnp.bfloat16))]
     if not m["tied"]:
         out["lm_head"] = [_bits(head_weights(config, key))]
     return out

@@ -99,6 +99,17 @@ def weights_changed(served):
     served.registry[served.name] = (model, served.engines[0].params)
 
 
+def final_norm_changed(served):
+    """A served leaf outside the decoder layers, the embedding and the
+    head is not the reference's: one bf16 step on the final norm."""
+    for e in served.engines:
+        norm = e.params["final_norm"]
+        e.params = dict(e.params, final_norm=norm.at[0].set(
+            jnp.nextafter(norm[0], jnp.asarray(2.0, norm.dtype))))
+    model = served.registry[served.name][0]
+    served.registry[served.name] = (model, served.engines[0].params)
+
+
 def test_a_sound_run_is_correct(tiny_cell):
     res = _run(tiny_cell)
     assert res["correct"], res["check"]
@@ -110,7 +121,8 @@ def test_a_sound_run_is_correct(tiny_cell):
 
 
 @pytest.mark.parametrize("fault", [token_altered, half_batch,
-                                   state_unchanged, weights_changed])
+                                   state_unchanged, weights_changed,
+                                   final_norm_changed])
 def test_a_broken_path_is_not_correct(tiny_cell, fault):
     res = _run(tiny_cell, fault=fault)
     assert not res["correct"], res["check"]

@@ -35,7 +35,8 @@ ATOL = 2e-4
 
 
 def _config(family):
-    return dict(FAMILIES[family], name=f"tiny-{family}", source="test")
+    return dict(FAMILIES[family], name=f"tiny-{family}", source="test",
+                reference="dense_gqa")
 
 
 def _program(config, key):
@@ -67,6 +68,20 @@ def test_reference_draws_the_served_weights(family):
     head = served["embed"].T if config["tie_word_embeddings"] \
         else served["lm_head"]
     same(dense_gqa.head_weights(config, key), head)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_checksums_cover_every_served_leaf(family):
+    """The harness checksums every leaf the program serves, and the
+    reference gives each of them, bit for bit."""
+    from bench.harness.main import _checksums
+    config = _config(family)
+    key = jax.random.key(9)
+    _, served = _program(config, key)
+    sums = _checksums(served)
+    assert len(sums) == len(jax.tree.leaves(served))
+    assert "final_norm" in sums
+    assert sums == dense_gqa.checksums(config, key)
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))

@@ -5,6 +5,7 @@ import re
 
 import pytest
 
+from bench.harness import check as check_lib
 from bench.harness import spec
 
 BENCH = spec.load_benchmark()
@@ -48,6 +49,31 @@ def test_every_cell_resolves_and_reports_enough(cell):
     assert limits["max_logit_gap"]["limit"] > 0
 
 
+# widths, which a configuration never cuts (the model-configs guide,
+# section 4): hidden, intermediate and expert, latent, state, projection
+# and head sizes, ranks, the window, the expansion factor and the experts
+# each token takes
+WIDTH_SUFFIXES = ("_dim", "_rank", "hidden_size", "intermediate_size",
+                  "state_size", "head_size", "proj_size")
+WIDTH_KEYS = ("sliding_window", "expand", "num_experts_per_tok")
+VOCAB_SHARE = 8   # a configuration keeps at least 1/8 of the vocabulary
+
+
+def is_width(key):
+    return key.endswith(WIDTH_SUFFIXES) or key in WIDTH_KEYS
+
+
+@pytest.mark.parametrize("key, width", [
+    ("head_dim", True), ("kv_lora_rank", True), ("hidden_size", True),
+    ("intermediate_size", True), ("moe_intermediate_size", True),
+    ("shared_expert_intermediate_size", True), ("sliding_window", True),
+    ("state_size", True), ("num_experts_per_tok", True), ("expand", True),
+    ("vocab_size", False), ("num_hidden_layers", False),
+    ("num_experts", False), ("logits_scaling", False)])
+def test_the_width_rule(key, width):
+    assert is_width(key) is width
+
+
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_files_state_their_cuts(config):
     data = json.loads((spec.ROOT / config["file"]).read_text())
@@ -56,7 +82,23 @@ def test_config_files_state_their_cuts(config):
     assert data["reduced"] == config["reduced"]
     for key in config["reduced"]:
         assert key in data and key in data["published"]
-        assert not key.endswith(("_dim", "_rank", "_size"))
+        assert not is_width(key), key
+    if "vocab_size" in config["reduced"]:
+        assert data["vocab_size"] * VOCAB_SHARE \
+            >= data["published"]["vocab_size"]
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_reference_names_a_reference_and_an_architecture(config):
+    data = json.loads((spec.ROOT / config["file"]).read_text())
+    name = data["reference"]
+    assert (spec.BENCH_DIR / "reference" / f"{name}.py").is_file()
+    assert (spec.BENCH_DIR / "arch" / f"{name}.py").is_file()
+    arch = spec.arch_module(data)
+    for fn in ("model_config", "model_flops", "decode_attention",
+               "prefill_attention"):
+        assert callable(getattr(arch, fn)), fn
+    assert callable(check_lib.reference_module(data).dims)
 
 
 def test_the_benchmark_lives_under_its_paths():
