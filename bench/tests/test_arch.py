@@ -2,14 +2,21 @@
 gives the program's ``ModelConfig`` and the work counts the readers of
 ``mfu``, ``mfu.prefill`` and the two kernel rooflines use.
 
-For both configurations the readers, through ``ctx.arch``, read exactly
-what the dense formulas they had before gave with the reference's
-``dims``.  A toy MoE architecture, put in place here as modules in
-``sys.modules``, resolves through ``spec.model_config``, the program's
-``build_model`` and the readers, with no file of the harness changed.
+Every configuration, whatever its family, keeps one contract: its layer
+and vocabulary counts as its file states them, and work counts that are
+finite, never negative, nought for no work and grouped over no more
+layers than it has.  The dense family's own checks run over the
+configurations whose file names ``dense_gqa``: there the readers, through
+``ctx.arch``, read exactly what the dense formulas they had before gave
+with the reference's ``dims``.  A toy MoE architecture, put in place here
+as modules in ``sys.modules``, keeps the contract and resolves through
+``spec.model_config``, the program's ``build_model`` and the readers,
+with no file of the harness changed.
 """
 import importlib.util
 import json
+import math
+import numbers
 import sys
 import types
 
@@ -25,7 +32,17 @@ from bench.harness.serving import RoundWork, make_weights
 from bench.harness.trace import Event, Trace
 
 BENCH = spec.load_benchmark()
-CONFIGS = [c["name"] for c in BENCH["configs"]]
+DATA = {c["name"]: json.loads((spec.ROOT / c["file"]).read_text())
+        for c in BENCH["configs"]}
+CONFIGS = list(DATA)
+
+
+def dense_only(configs):
+    """The configuration files of the dense GQA family."""
+    return [c for c in configs if c["reference"] == "dense_gqa"]
+
+
+DENSE = [c["name"] for c in dense_only(DATA.values())]
 READERS = ["mfu", "mfu.prefill", "paged_decode_roofline",
            "paged_prefill_roofline"]
 PEAK = work.peaks("TPU v5 lite")
@@ -118,10 +135,9 @@ BEFORE = {
 
 
 @pytest.mark.parametrize("reader", READERS)
-@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize("config", DENSE)
 def test_readers_through_the_arch_equal_the_dense_formulas(config, reader):
-    cfg = next(c for c in BENCH["configs"] if c["name"] == config)
-    data = json.loads((spec.ROOT / cfg["file"]).read_text())
+    data = DATA[config]
     dims = check_lib.reference_module(data).dims(data)
     got = _reader(reader)(_ctx(dims, spec.arch_module(data)))
     want = BEFORE[reader](dims)
@@ -129,10 +145,9 @@ def test_readers_through_the_arch_equal_the_dense_formulas(config, reader):
     assert got == want
 
 
-@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize("config", DENSE)
 def test_dense_gqa_reads_the_published_keys(config):
-    cfg = next(c for c in BENCH["configs"] if c["name"] == config)
-    data = json.loads((spec.ROOT / cfg["file"]).read_text())
+    data = DATA[config]
     mc = spec.model_config(data)
     assert mc.arch_type == "dense" and mc.moe is None
     assert (mc.num_layers, mc.d_model, mc.num_heads, mc.num_kv_heads,
@@ -293,3 +308,55 @@ def test_the_readers_use_the_architectures_counts(toy_moe, reader):
     # and not what a dense model of the same widths would count
     dense = dict(d, ff=d["k"] * d["expert_ff"])
     assert got != pytest.approx(BEFORE[reader](dense), rel=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the contract every family keeps
+# ---------------------------------------------------------------------------
+
+def _has_work(w):
+    return any(e > s for s, e in w.prefill_spans) or bool(
+        w.decode_contexts) or w.produced > 0
+
+
+def _check_groups(groups, layers):
+    assert groups is not None
+    for n, f, b in groups:
+        assert isinstance(n, numbers.Integral) and not isinstance(
+            n, bool) and n > 0
+        assert math.isfinite(f) and f >= 0
+        assert math.isfinite(b) and b >= 0
+    assert sum(n for n, _, _ in groups) <= layers
+
+
+@pytest.mark.parametrize("config", CONFIGS + [TOY["name"]])
+def test_every_family_keeps_the_contract(config, request):
+    if config == TOY["name"]:
+        request.getfixturevalue("toy_moe")
+        data = TOY
+    else:
+        data = DATA[config]
+    layers = data["num_hidden_layers"]
+    arch = spec.arch_module(data)
+    dims = check_lib.reference_module(data).dims(data)
+    mc = spec.model_config(data)
+    assert (mc.num_layers, mc.vocab_size) == (layers, data["vocab_size"])
+    assert dims["layers"] == layers
+    assert any(not _has_work(w) for w in ROUNDS)
+    for w in ROUNDS:
+        flops = arch.model_flops(dims, w.prefill_spans, w.decode_contexts,
+                                 w.produced)
+        assert math.isfinite(flops)
+        assert flops > 0 if _has_work(w) else flops == 0
+        _check_groups(arch.decode_attention(dims, w.decode_contexts), layers)
+        _check_groups(arch.prefill_attention(dims, w.prefill_spans), layers)
+    for groups in (arch.decode_attention(dims, []),
+                   arch.prefill_attention(dims, [])):
+        _check_groups(groups, layers)
+        assert all(f == 0 and b == 0 for _, f, b in groups)
+
+
+def test_the_dense_checks_leave_other_families_out():
+    chosen = dense_only([*DATA.values(), TOY])
+    assert TOY not in chosen
+    assert [c["name"] for c in chosen] == DENSE

@@ -9,6 +9,8 @@ The tiny model serves bf16 weights on the CPU, so its gaps are not the
 chip's: the limit here is this size's own, set the same way (above what
 sound runs read, below what the faults and the control read).
 """
+import dataclasses
+
 import jax.numpy as jnp
 import pytest
 
@@ -129,7 +131,14 @@ def test_a_broken_path_is_not_correct(tiny_cell, fault):
 
 
 def test_the_control_reads_wider_than_the_program(tiny_cell):
-    res = _run(tiny_cell, control=True)
+    # every request finished and every served token compared, so the
+    # sample does not hang on how far a loaded CPU got, nor on which slot
+    # served which request
+    traffic = tiny_cell.traffic
+    cell = dataclasses.replace(tiny_cell, traffic=dict(
+        traffic, drain_s=10.0,
+        check_tokens=12 * traffic["output_tokens"]["max"]))
+    res = _run(cell, control=True)
     assert res["correct"] and res["control"]["correct"] is False
     assert res["control"]["max_logit_gap"]["value"] \
         > TINY_LIMIT["max_logit_gap"]["limit"] \
